@@ -101,8 +101,8 @@ def mesh_axes_for(pods: int) -> tuple[str, ...]:
 
 
 def make_mesh_for(pods: int, data: int = 1, model: int = 1):
-    """Build the jax Mesh for a pool size (delegates to launch.mesh so the
-    jax-version compat shim lives in exactly one place)."""
+    """Build the jax Mesh for a pool size (delegates to launch.mesh, the
+    one place meshes are constructed)."""
     from repro.launch.mesh import make_mesh
 
     return make_mesh(mesh_shape_for(pods, data, model), mesh_axes_for(pods))

@@ -82,6 +82,27 @@ def _pack_weights() -> jax.Array:
     )
 
 
+def _tile_count(mask: jax.Array) -> jax.Array:
+    """A tile's significant-element count, broadcast over one
+    (SUBLANES, LANES) int32 block: the smallest output block the TPU
+    lowering accepts (a (1, 1) block is refused)."""
+    return jnp.broadcast_to(
+        jnp.sum(mask.astype(jnp.int32)), (SUBLANES, LANES)
+    )
+
+
+_COUNT_BLOCK = pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0))
+
+
+def _count_shape(n_tiles: int) -> jax.ShapeDtypeStruct:
+    return jax.ShapeDtypeStruct((n_tiles * SUBLANES, LANES), jnp.int32)
+
+
+def _sum_counts(cnt: jax.Array) -> jax.Array:
+    """Total of the per-tile counts: element [0, 0] of each count block."""
+    return jnp.sum(cnt[::SUBLANES, 0])
+
+
 def _pack_kernel(x_ref, q_ref, bits_ref, cnt_ref, res_ref):
     """One (block_rows, LANES) tile: quantize, pack mask bits, count,
     and fold the error-feedback residual — one read, four writes."""
@@ -95,7 +116,7 @@ def _pack_kernel(x_ref, q_ref, bits_ref, cnt_ref, res_ref):
         preferred_element_type=jnp.float32,
     )
     bits_ref[...] = bytes_f.astype(jnp.int32)
-    cnt_ref[0, 0] = jnp.sum(mask.astype(jnp.int32))
+    cnt_ref[...] = _tile_count(mask)
 
 
 @functools.partial(
@@ -137,18 +158,18 @@ def wire_pack(
         out_specs=[
             block,
             block,
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            _COUNT_BLOCK,
             block,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, LANES), vdt),
             jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((grid[0], 1), jnp.int32),
+            _count_shape(grid[0]),
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         ],
         interpret=interpret,
     )(x2)
-    nnz = jnp.sum(cnt)
+    nnz = _sum_counts(cnt)
     mask_bytes = (
         bits2[:, :BYTES_PER_ROW].astype(jnp.uint8).reshape(-1)[: (n + 7) // 8]
     )
@@ -169,7 +190,7 @@ def wire_pack(
 
 
 def _nnz_kernel(x_ref, cnt_ref):
-    cnt_ref[0, 0] = jnp.sum((x_ref[...] != 0).astype(jnp.int32))
+    cnt_ref[...] = _tile_count(x_ref[...] != 0)
 
 
 @functools.partial(
@@ -192,11 +213,11 @@ def wire_nnz(
         _nnz_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], 1), jnp.int32),
+        out_specs=_COUNT_BLOCK,
+        out_shape=_count_shape(grid[0]),
         interpret=interpret,
     )(x2)
-    return jnp.sum(cnt)
+    return _sum_counts(cnt)
 
 
 def _unpack_kernel(b_ref, bits_ref):

@@ -1,5 +1,6 @@
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     os.environ.get("DRYRUN_XLA_FLAGS")
     or "--xla_force_host_platform_device_count=512"
@@ -7,10 +8,11 @@ os.environ["XLA_FLAGS"] = (
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST stay the first executable statements — jax locks
-the device count at first init, and the production meshes need 512 host
-placeholder devices. Do NOT export this flag anywhere else (smoke tests and
-benchmarks must see 1 device).
+The lines above MUST stay the first executable statements — jax locks
+the platform and the device count at first init, and the production
+meshes need 512 host placeholder devices (on a TPU host too: the CPU pin
+keeps the dry-run off the chip). Do NOT export this flag anywhere else
+(smoke tests and benchmarks must see 1 device).
 
 Per cell this driver:
   1. builds the mesh (16x16 single-pod or 2x16x16 multi-pod),
@@ -232,8 +234,6 @@ def lower_cell(
 def analyze(compiled, cell, mesh, mode: str) -> dict:
     mem = compiled.memory_analysis()
     xla_cost = compiled.cost_analysis()
-    if isinstance(xla_cost, (list, tuple)):  # jax 0.4.x returns [dict]
-        xla_cost = xla_cost[0] if xla_cost else {}
     hlo = compiled.as_text()
     # trip-count-aware analysis: XLA's cost_analysis visits while bodies
     # ONCE, undercounting every scanned layer (launch/hloanalysis.py)

@@ -14,32 +14,12 @@ Mesh axes:
 
 from __future__ import annotations
 
-import enum
-from typing import Optional
-
 import jax
-
-try:  # jax >= 0.5: explicit-sharding axis types
-    from jax.sharding import AxisType
-
-    _HAS_AXIS_TYPES = True
-except ImportError:  # jax 0.4.x: every mesh axis is implicitly Auto
-
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    _HAS_AXIS_TYPES = False
+from jax.sharding import AxisType
 
 
 def _mk(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """jax.make_mesh across the 0.4/0.5 axis_types API change."""
-    if _HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            shape, axes, axis_types=(AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

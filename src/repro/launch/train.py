@@ -58,6 +58,7 @@ from repro.dist.compression import (
     apply_combined,
     isp_compressed_step,
 )
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.config import ArchConfig, BlockSpec, FF, Mixer, uniform_groups
 from repro.models.transformer import LM
 from repro.optim import apply_updates, clip_by_global_norm
@@ -434,6 +435,7 @@ def train(args) -> dict:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:
             json.dump(result, f, indent=1)
+    result["params"] = st.params  # final parameters, for in-process callers
     return result
 
 
@@ -647,7 +649,8 @@ def train_faas(args) -> dict:
     return result
 
 
-def main() -> None:
+def parse_args(argv: Optional[list[str]] = None) -> argparse.Namespace:
+    """The driver's command line (``argv`` None reads ``sys.argv``)."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--runtime", default="inproc",
                     choices=tuple(sorted(RUNTIMES)),
@@ -746,9 +749,15 @@ def main() -> None:
     ap.add_argument("--pool-budget", type=int, default=None,
                     help="faas --jobs: max concurrent active (worker, job) "
                     "pairs; the scheduler evicts fair-share beyond it")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def main() -> None:
+    use_compile_cache()
+    args = parse_args()
     res = RUNTIMES[args.runtime](args)
-    slim = {k: v for k, v in res.items() if k not in ("history", "updates")}
+    slim = {k: v for k, v in res.items()
+            if k not in ("history", "updates", "params")}
     if isinstance(slim.get("jobs"), dict):  # fleet: per-job histories too
         slim["jobs"] = {
             j: {k: v for k, v in r.items() if k != "history"}

@@ -30,8 +30,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
-
 from repro.models.config import ArchConfig
 from repro.models.params import ParamDef
 
@@ -226,7 +224,7 @@ def moe_apply(
         # the virtual-expert dim (the ZeRO gather over 'data' still happens
         # outside, but the 16x larger 'model' gather disappears)
         w_spec = P("model", None, None) if a2a else P(None, None, None)
-        out = shard_map(
+        out = jax.shard_map(
             dispatch_ff_combine,
             mesh=mesh,
             in_specs=(

@@ -29,8 +29,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
-
 from repro.models.config import ArchConfig
 from repro.models.params import ParamDef
 
@@ -303,7 +301,7 @@ def slstm_apply(
             # every step chip-local by construction.
             from jax.sharding import PartitionSpec as P
 
-            state, hs = shard_map(
+            state, hs = jax.shard_map(
                 run_scan,
                 mesh=mesh,
                 in_specs=(

@@ -267,12 +267,13 @@ class FleetScheduler:
         src = os.path.dirname(os.path.abspath(pkg_dir))
         env = dict(os.environ)
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+        # workers and brokers are host processes: on a machine with a chip
+        # they must never reach for it (a chip belongs to one process)
+        env["JAX_PLATFORMS"] = "cpu"
         return env
 
     def _worker_env(self) -> dict:
         env = self._base_env()
-        if all(self.jobs[j].cfg.force_cpu for j in self.job_ids):
-            env["JAX_PLATFORMS"] = "cpu"
         env.setdefault("XLA_FLAGS", "--xla_cpu_multi_thread_eigen=false "
                        "intra_op_parallelism_threads=1")
         env.setdefault("OMP_NUM_THREADS", "1")

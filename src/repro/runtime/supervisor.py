@@ -165,7 +165,6 @@ class FaaSJobConfig:
     deadline_s: float = 600.0
     pull_deadline_s: float = 120.0
     broker_spawn_timeout_s: float = 30.0
-    force_cpu: bool = True
     seed: int = 0
 
     def compiled_chaos_plan(self) -> Optional[FaultPlan]:
@@ -508,12 +507,13 @@ class Supervisor:
         src = os.path.dirname(os.path.abspath(pkg_dir))
         env = dict(os.environ)
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+        # workers and brokers are host processes: on a machine with a chip
+        # they must never reach for it (a chip belongs to one process)
+        env["JAX_PLATFORMS"] = "cpu"
         return env
 
     def _worker_env(self) -> dict:
         env = self._base_env()
-        if self.cfg.force_cpu:
-            env["JAX_PLATFORMS"] = "cpu"
         if self.cfg.hostperf:
             # tuned launch env (launch/hostperf.py): tcmalloc preload when
             # available, pinned XLA host flags, full thread-cap family; what

@@ -173,6 +173,7 @@ def run_worker(
     from repro.checkpoint import store as ckpt
     from repro.core import isp as isp_lib
     from repro.dist.elastic import reintegrate_into
+    from repro.launch.compile_cache import use_compile_cache
     from repro.runtime import protocol, sharding
     from repro.runtime import workload as workload_lib
 
@@ -250,19 +251,14 @@ def run_worker(
     if wfaults is not None:
         wfaults.install()
 
-    # persistent jit cache under the run dir: later invocations (respawns,
-    # invocation boundaries, every worker after the first) load compiled
+    # persistent jit cache: later invocations (respawns, invocation
+    # boundaries, every worker after the first, later jobs) load compiled
     # step functions instead of re-paying the ~1 s XLA cold start — the
-    # standard warm-container trick for FaaS runtimes (cuts the measured
-    # cold-start share of BENCH_runtime.json's step-time mean)
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.join(job["run_dir"], "jit_cache"),
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
-        pass  # older jax: cold starts stay, correctness unaffected
+    # standard warm-container trick for FaaS runtimes.  Small step
+    # functions compile in well under the default 1 s threshold, so every
+    # compile is cached
+    use_compile_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
     wl = workload_lib.build(job["workload"], job["workload_cfg"])
     optimizer = optim.make(job["optimizer"], job["lr"])

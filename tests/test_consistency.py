@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core import consistency as cons
 from repro.core.isp import ISPConfig
@@ -63,7 +63,9 @@ def test_ssp_drain_flushes_queue():
 @given(P=st.integers(min_value=2, max_value=4),
        slack=st.integers(min_value=1, max_value=4),
        seed=st.integers(min_value=0, max_value=50))
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)  # each new (P, slack) compiles
+@example(P=2, slack=4, seed=15)
+@example(P=4, slack=3, seed=40)
 def test_ssp_visibility_bound_property(P, slack, seed):
     """SSP contract, for ANY (P, slack): an update produced at step t is
     fully visible by step t + slack."""
@@ -85,7 +87,9 @@ def test_ssp_visibility_bound_property(P, slack, seed):
        slack=st.integers(min_value=1, max_value=4),
        n_steps=st.integers(min_value=1, max_value=5),
        seed=st.integers(min_value=0, max_value=50))
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)  # each new (P, slack) compiles
+@example(P=4, slack=2, n_steps=5, seed=19)
+@example(P=2, slack=4, n_steps=2, seed=39)
 def test_ssp_drain_conserves_mass_property(P, slack, n_steps, seed):
     """The delay queue never loses or duplicates update mass: everything
     made visible across the steps plus ``ssp_drain``'s remainder equals

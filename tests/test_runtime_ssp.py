@@ -193,20 +193,27 @@ def test_ssp_sigkill_respawn_stays_bit_identical(tmp_path):
     params still equal the reference — the t - slack - 1 bound held
     through the crash (a violation would change what the respawned
     replica saw, and the bit-compare would catch it)."""
+    # the victim must still be running when the kill lands: with warm
+    # compiles the whole job can finish before the supervisor sees the
+    # trigger step, so the victim is slowed (timing only — SSP delivery is
+    # a pure function of the step) and runs more steps than the harness's 8
+    steps = 12
     cfg = small_pmf_cfg(
         tmp_path / "job", consistency="ssp", slack=SLACK,
-        checkpoint_every=4, kill_worker_at_step=(1, 5),
+        total_steps=steps, checkpoint_every=4, kill_worker_at_step=(1, 5),
+        straggler={"worker": 1, "delay_s": 0.2, "every": 1},
         deadline_s=240.0,
     )
     res = run_job(cfg)
     assert res["n_respawns"] >= 1
     assert res["respawns"][0]["worker"] == 1
-    assert res["steps"] == STEPS
+    assert res["steps"] == steps
     assert res["dup_mismatches"] == 0
-    _ref, ref_final = reference_updates(consistency="ssp", slack=SLACK)
+    _ref, ref_final = reference_updates(
+        steps=steps, consistency="ssp", slack=SLACK)
     for w in range(P):
         step, live = final_params(cfg, w)
-        assert step == STEPS + 1
+        assert step == steps + 1
         for a, b in zip(jax.tree.leaves(ref_final[w]), jax.tree.leaves(live)):
             np.testing.assert_array_equal(
                 np.asarray(a), np.asarray(b),

@@ -103,6 +103,23 @@ class TrainState:
     pool: int  # current worker count (elastic weak scaling)
 
 
+# The phases of a train step, each a ``jax.named_scope`` in ``make_step`` /
+# ``make_pod_step``.  A scope is trace-time metadata: it reaches every HLO
+# instruction of its phase as ``op_name="jit(step_fn)/<phase>/..."`` (under
+# the pod vmap, ``jit(step_fn)/vmap(<phase>)/...``), so a profile can split
+# device time by phase, and it leaves the computation as it is.  JAX's
+# persistent compile cache hashes the module without this debug info, so an
+# entry compiled before a scope changed comes back with the old metadata.
+PHASES = ("fwd_bwd", "clip", "optimizer", "apply", "isp_split", "sent_count",
+          "exchange")
+
+# The parts of one iteration of ``train()``'s loop, each a
+# ``jax.profiler.TraceAnnotation`` on the profiler's clock.  With no profiler
+# running an annotation costs a branch.
+LOOP_SPANS = ("train.input", "train.dispatch", "train.sync", "train.log",
+              "train.checkpoint", "train.scale_in")
+
+
 def make_step(lm: LM, optimizer, isp: Optional[ISPConfig], clip: float = 1.0):
     """One jitted train step for a fixed pool size.
 
@@ -113,31 +130,38 @@ def make_step(lm: LM, optimizer, isp: Optional[ISPConfig], clip: float = 1.0):
     """
 
     def step_fn(params, opt_state, residual, batch):
-        (loss, metrics), grads = jax.value_and_grad(
-            lm.train_loss, has_aux=True
-        )(params, batch)
+        with jax.named_scope("fwd_bwd"):
+            (loss, metrics), grads = jax.value_and_grad(
+                lm.train_loss, has_aux=True
+            )(params, batch)
         if clip:
-            grads = clip_by_global_norm(grads, clip)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
+            with jax.named_scope("clip"):
+                grads = clip_by_global_norm(grads, clip)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
         if isp is None:
-            params = apply_updates(params, updates)
+            with jax.named_scope("apply"):
+                params = apply_updates(params, updates)
             sent_frac = jnp.asarray(1.0, jnp.float32)
         else:
             from repro.core.isp import significance_split
 
-            v_t = isp.threshold(opt_state.step)
+            with jax.named_scope("isp_split"):
+                v_t = isp.threshold(opt_state.step)
 
-            def split(u, x, r):
-                return significance_split(r + u, x, v_t, isp.absolute_floor)
+                def split(u, x, r):
+                    return significance_split(r + u, x, v_t,
+                                              isp.absolute_floor)
 
-            out = jax.tree.map(split, updates, params, residual)
-            td = jax.tree.structure(params)
-            ls = td.flatten_up_to(out)
-            sig = td.unflatten([l[0] for l in ls])
-            residual = td.unflatten([l[1] for l in ls])
-            masks = td.unflatten([l[2] for l in ls])
-            params = apply_updates(params, sig)
-            sent_frac = communicated_fraction(masks)
+                out = jax.tree.map(split, updates, params, residual)
+                td = jax.tree.structure(params)
+                ls = td.flatten_up_to(out)
+                sig = td.unflatten([l[0] for l in ls])
+                residual = td.unflatten([l[1] for l in ls])
+                masks = td.unflatten([l[2] for l in ls])
+                params = apply_updates(params, sig)
+            with jax.named_scope("sent_count"):
+                sent_frac = communicated_fraction(masks)
         return params, opt_state, residual, loss, sent_frac
 
     return jax.jit(step_fn, donate_argnums=(0, 1, 2))
@@ -176,21 +200,25 @@ def make_pod_step(
         )
 
         def pod_fn(opt_state, b):
-            (loss, metrics), grads = jax.value_and_grad(
-                lm.train_loss, has_aux=True
-            )(params, b)
+            with jax.named_scope("fwd_bwd"):
+                (loss, metrics), grads = jax.value_and_grad(
+                    lm.train_loss, has_aux=True
+                )(params, b)
             if clip:
-                grads = clip_by_global_norm(grads, clip)
-            updates, opt_state = optimizer.update(grads, opt_state, params)
+                with jax.named_scope("clip"):
+                    grads = clip_by_global_norm(grads, clip)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = optimizer.update(grads, opt_state, params)
             return updates, opt_state, loss
 
         updates, opt_pod, losses = jax.vmap(pod_fn)(opt_pod, batch_p)
-        v_t = isp.threshold(opt_pod.step[0])
-        combined, res_pod, stats = isp_compressed_step(
-            comp, updates, params, res_pod, v_t,
-            floor=isp.absolute_floor,
-        )
-        params = apply_combined(params, combined)
+        with jax.named_scope("exchange"):
+            v_t = isp.threshold(opt_pod.step[0])
+            combined, res_pod, stats = isp_compressed_step(
+                comp, updates, params, res_pod, v_t,
+                floor=isp.absolute_floor,
+            )
+            params = apply_combined(params, combined)
         return (params, opt_pod, res_pod, jnp.mean(losses),
                 stats["sent_fraction"])
 
@@ -375,43 +403,52 @@ def train(args) -> dict:
     step_fn = build_step(st.pool)
     history = []
     worker_seconds = 0.0
-    t_job0 = time.time()
+    t_job0 = time.perf_counter()
+    span = jax.profiler.TraceAnnotation
 
     while st.step < args.steps:
-        # weak scaling (paper §3.2): global batch = pool * per-worker batch
-        gb = plan.global_batch(st.pool)
-        pipe = TokenPipeline(cfg.vocab_size, args.seq, gb, seed=args.seed)
-        batch = pipe.next_batch(st.step)
-        t0 = time.time()
-        st.params, st.opt_state, st.residual, loss, sent = step_fn(
-            st.params, st.opt_state, st.residual, batch
-        )
-        loss = float(loss)
-        dt = time.time() - t0
+        with span("train.input"):
+            # weak scaling (paper §3.2): global batch = pool * per-worker batch
+            gb = plan.global_batch(st.pool)
+            pipe = TokenPipeline(cfg.vocab_size, args.seq, gb, seed=args.seed)
+            batch = pipe.next_batch(st.step)
+        t0 = time.perf_counter()
+        with span("train.dispatch"):
+            st.params, st.opt_state, st.residual, loss, sent = step_fn(
+                st.params, st.opt_state, st.residual, batch
+            )
+        with span("train.sync"):
+            loss, sent = float(loss), float(sent)
+        dt = time.perf_counter() - t0
         worker_seconds += dt * st.pool
         st.step += 1
-        history.append(
-            {"step": st.step, "loss": loss, "sent_fraction": float(sent),
-             "pool": st.pool, "step_s": dt}
-        )
-        if st.step % args.log_every == 0:
-            print(f"step {st.step:5d} pool={st.pool:2d} loss={loss:.4f} "
-                  f"sent={float(sent):.3f} {dt*1e3:.0f}ms")
+        with span("train.log"):
+            history.append(
+                {"step": st.step, "loss": loss, "sent_fraction": sent,
+                 "pool": st.pool, "step_s": dt}
+            )
+            if st.step % args.log_every == 0:
+                print(f"step {st.step:5d} pool={st.pool:2d} loss={loss:.4f} "
+                      f"sent={sent:.3f} {dt*1e3:.0f}ms")
+            scale_in = False
+            if tuner is not None:
+                tuner.observe(st.step, loss, dt)
+                scale_in = tuner.decide().remove_worker and st.pool > 1
 
         if args.checkpoint_dir and st.step % args.checkpoint_every == 0:
-            save_checkpoint(args.checkpoint_dir, st)
+            with span("train.checkpoint"):
+                save_checkpoint(args.checkpoint_dir, st)
 
-        if tuner is not None:
-            tuner.observe(st.step, loss, dt)
-            if tuner.decide().remove_worker and st.pool > 1:
+        if scale_in:
+            with span("train.scale_in"):
                 # elastic scale-in: reintegrate -> checkpoint -> re-lower,
                 # with the mode registry owning the transition semantics
                 st = mode.scale_in(args, st, plan, isp)
                 step_fn = build_step(st.pool)  # re-lower
-                print(f"  [autotuner] scale-in -> pool={st.pool} "
-                      f"(global batch {plan.global_batch(st.pool)})")
+            print(f"  [autotuner] scale-in -> pool={st.pool} "
+                  f"(global batch {plan.global_batch(st.pool)})")
 
-    wall = time.time() - t_job0
+    wall = time.perf_counter() - t_job0
     # bill the modelled topology the job declares (paper: one Redis VM per
     # update-store shard), not a hardcoded single shard
     bill = faas_cost(

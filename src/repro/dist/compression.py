@@ -131,29 +131,60 @@ def split_significant(
     return sig, res
 
 
+# Rows of the TPU's (8, 128) memory tile. A leaf's blocks taken as
+# (_TILE_ROWS, block) tiles of its last two axes form a view that XLA lays
+# out as the leaf itself, so the block argmax reads the leaf where it lies;
+# the plain (..., last // block, block) view is relaid out per leaf first.
+_TILE_ROWS = 8
+
+
 def _block_topk_mask(sig: jax.Array, cfg: CompressionConfig) -> jax.Array:
     """Boolean keep-mask of the per-block top-k |entries| of one pod slice.
 
-    Flattens to (nb, block) with zero padding; padded entries have |0| and
-    can only be selected when a block is all-zero, where keeping them is a
-    no-op (0 moves 0 mass). Returns a mask of ``sig.shape``.
+    A block is ``block`` consecutive entries of the row-major flatten, and
+    each block is selected on its own, so the order in which they are laid
+    out does not matter. ``k == 1`` keeps the block's argmax: one fused
+    reduction, where ``lax.top_k`` lowers to a full sort; both keep the
+    lowest index among equal magnitudes, so the masks are equal bit for bit.
+    It reads the blocks in place when the last axis is a multiple of
+    ``block`` and the one before it of ``_TILE_ROWS``. Otherwise, and for
+    ``k > 1``, the slice is flattened to (nb, block) with zero padding, and
+    padded entries are dropped from the mask (0 moves 0 mass either way).
+    Returns a mask of ``sig.shape``.
     """
     n = sig.size
     block = min(cfg.block, max(n, 1))
     k = cfg.k_per_block(block)
+    if (k == 1 and sig.ndim >= 2 and sig.shape[-2] % _TILE_ROWS == 0
+            and sig.shape[-1] % block == 0):
+        *lead, rows, last = sig.shape
+        tiles = sig.reshape(
+            *lead, rows // _TILE_ROWS, _TILE_ROWS, last // block, block
+        ).swapaxes(-3, -2)
+        keep = _argmax_mask(tiles)
+        return keep.swapaxes(-3, -2).reshape(sig.shape)
     flat = sig.reshape(-1)
     pad = (-n) % block
     if pad:
         flat = jnp.pad(flat, (0, pad))
     blocks = flat.reshape(-1, block)
-    _, idx = jax.lax.top_k(jnp.abs(blocks), k)  # (nb, k)
-    keep = jnp.zeros_like(blocks, dtype=jnp.bool_)
-    keep = jnp.put_along_axis(keep, idx, True, axis=-1, inplace=False)
+    if k == 1:
+        keep = _argmax_mask(blocks)
+    else:
+        _, idx = jax.lax.top_k(jnp.abs(blocks), k)  # (nb, k)
+        keep = jnp.zeros_like(blocks, dtype=jnp.bool_)
+        keep = jnp.put_along_axis(keep, idx, True, axis=-1, inplace=False)
     # padded entries are never real mass; drop them from the mask
     if pad:
         valid = (jnp.arange(flat.shape[0]) < n).reshape(-1, block)
         keep = keep & valid
     return keep.reshape(-1)[:n].reshape(sig.shape)
+
+
+def _argmax_mask(blocks: jax.Array) -> jax.Array:
+    """True at the first largest |entry| of each last-axis block."""
+    idx = jnp.argmax(jnp.abs(blocks), axis=-1)
+    return jnp.arange(blocks.shape[-1]) == idx[..., None]
 
 
 def topk_combine(cfg: CompressionConfig, sig_pod: PyTree, n_pods: int) -> PyTree:

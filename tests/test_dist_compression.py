@@ -16,6 +16,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.dist.compression import (
     CompressionConfig,
+    _block_topk_mask,
     apply_combined,
     isp_compressed_step,
     split_significant,
@@ -154,6 +155,79 @@ def test_topk_keeps_the_largest_magnitudes():
         np.asarray(res2["w"][0]),
         np.asarray([4.0, -0.1, 0.2, 0.0, 0.3, 0.0, -0.4, 0.5]),
     )
+
+
+def _sorted_topk_mask(sig, cfg):
+    """The block top-k mask as a full sort selects it: ``lax.top_k`` over
+    the zero-padded (nb, block) flatten, scattered into a zero mask."""
+    n = sig.size
+    block = min(cfg.block, max(n, 1))
+    flat = sig.reshape(-1)
+    pad = (-n) % block
+    if pad:
+        flat = jnp.pad(flat, (0, pad))
+    blocks = flat.reshape(-1, block)
+    _, idx = jax.lax.top_k(jnp.abs(blocks), cfg.k_per_block(block))
+    keep = jnp.put_along_axis(
+        jnp.zeros_like(blocks, dtype=jnp.bool_), idx, True, axis=-1,
+        inplace=False,
+    )
+    if pad:
+        keep = keep & (jnp.arange(flat.shape[0]) < n).reshape(-1, block)
+    return keep.reshape(-1)[:n].reshape(sig.shape)
+
+
+def _tied_slices(seed, n_pods, shape, dtype):
+    """Pod slices whose magnitudes tie often: halves, +0 and -0, and a
+    leading all-zero block (mixed-sign zeros) in every slice."""
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    full = (n_pods,) + shape
+    x = jnp.round(2.0 * jax.random.normal(k1, full)) / 2.0
+    zero = jnp.where(jax.random.bernoulli(k2, 0.5, full), -0.0, 0.0)
+    x = jnp.where(jax.random.bernoulli(k3, 0.3, full), zero, x)
+    flat = x.reshape(n_pods, -1)
+    head = min(128, flat.shape[1])
+    flat = flat.at[:, :head].set(zero.reshape(n_pods, -1)[:, :head])
+    return flat.reshape(full).astype(dtype)
+
+
+@pytest.mark.parametrize("budget", [0.01, 0.05], ids=["k1", "k6"])
+@pytest.mark.parametrize("n_pods", [1, 4])
+@pytest.mark.parametrize("dtype", list(DTYPES))
+@pytest.mark.parametrize(
+    "shape",
+    [(300,), (3, 100), (60,), (2, 30), (16, 256), (2, 8, 384), (4, 256)],
+    ids=["pad", "pad-2d", "small", "small-2d", "tiles", "tiles-3d",
+         "last-axis-rows"],
+)
+def test_block_mask_equals_sorted_top_k(shape, dtype, n_pods, budget):
+    """The block mask equals the full-sort top-k mask bit for bit, ties,
+    signed zeros and all-zero blocks included, on every path: tiles of
+    the last two axes, the padded flatten, a leaf smaller than a block.
+    Only k > 1 still sorts."""
+    cfg = CompressionConfig(scheme="topk", budget=budget, block=128)
+    sig = _tied_slices(len(shape) + sum(shape), n_pods, shape, DTYPES[dtype])
+    got = jax.vmap(lambda s: _block_topk_mask(s, cfg))(sig)
+    want = jax.vmap(lambda s: _sorted_topk_mask(s, cfg))(sig)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    k = cfg.k_per_block(min(cfg.block, sig[0].size))
+    jaxpr = str(jax.make_jaxpr(lambda s: _block_topk_mask(s, cfg))(sig[0]))
+    assert ("top_k" in jaxpr) == (k > 1)
+
+
+@pytest.mark.parametrize("budget,sorts", [(0.01, False), (0.05, True)])
+def test_topk_step_sorts_only_when_k_exceeds_one(budget, sorts):
+    """At 1% of 128 (k = 1) the compiled exchange holds no ``sort``; at 5%
+    (k = 6) it does. The selection is static: only the program shows it."""
+    cfg = CompressionConfig(scheme="topk", budget=budget, block=128)
+    P = 2
+    shapes = {"tiles": (16, 256), "pad": (300,)}
+    params = {k: jnp.ones(s, jnp.bfloat16) for k, s in shapes.items()}
+    pod = {k: jnp.ones((P,) + s, jnp.bfloat16) for k, s in shapes.items()}
+    hlo = jax.jit(
+        lambda u, x, r: isp_compressed_step(cfg, u, x, r, jnp.float32(0.5))
+    ).lower(pod, params, pod).compile().as_text()
+    assert (" sort(" in hlo) == sorts
 
 
 # -- error-feedback conservation ---------------------------------------------

@@ -85,7 +85,7 @@ class Cell:
 
     @property
     def run(self) -> dict:
-        return self.cfg["run"]
+        return sizes(self.cfg)
 
     @property
     def rows(self) -> int:
@@ -111,33 +111,131 @@ def load_cell(name: str) -> Cell:
 # ---- the program side ---------------------------------------------------------
 
 
-def program_arch(cfg: dict):
-    """The program's ArchConfig for a configuration file: the program's own
-    architecture with the file's depth and vocabulary; every width is held
-    to the file's."""
-    from repro.configs import get_arch, get_smoke
-    from repro.models.config import uniform_groups
+def sizes(cfg: dict) -> dict:
+    """The sizes the reference and the counts are built from: the file's
+    ``run``.  Where the file cuts the experts held here (``num_experts`` in
+    ``reduced``), the published count is the router's width,
+    ``num_experts_total``."""
+    run = dict(cfg["run"])
+    if "num_experts" in run:
+        run.setdefault("num_experts_total", cfg.get("reduced", {}).get(
+            "num_experts", run["num_experts"]))
+    return run
 
-    run = cfg["run"]
-    program = cfg["program"]
-    # "smoke" names the program's own small same-family configuration, for
-    # the CPU tests of the harness
-    base = (get_smoke if program.get("smoke") else get_arch)(program["arch"])
-    (spec,), _ = base.groups[0]
-    arch = dataclasses.replace(
-        base, name=cfg["name"], vocab_size=run["vocab_size"],
-        groups=uniform_groups(spec, run["num_hidden_layers"]))
+
+# what the program's expert layer does and no key of its ArchConfig states;
+# tests hold both to the program: its loss is xent + 0.01 * aux
+# (``LM.train_loss``), and ``moe_apply`` divides the top-k gates by their sum
+PROGRAM_AUX_COEF = 0.01
+PROGRAM_NORM_TOPK_PROB = True
+
+
+def program_arch(cfg: dict, base=None):
+    """The program's ArchConfig for a configuration file: the program's own
+    architecture (``base``, else the one ``program`` names) with the file's
+    depth and vocabulary.  Its
+    first superblock is the period, and the depth a whole number of
+    periods.  Every key the file states that the program's blocks carry is
+    held to the file's, and then the program's parameter tree to the
+    reference's layout: names, shapes and dtypes."""
+    import jax
+    import jax.numpy as jnp
+
+    from bench.reference import model
+    from repro.configs import get_arch, get_smoke
+    from repro.models import params as pdefs
+    from repro.models.config import FF, Mixer, pattern_groups
+    from repro.models.transformer import LM
+
+    run, blocks = sizes(cfg), cfg["blocks"]
+    name = cfg["name"]
+    if base is None:
+        # "smoke" names the program's own small same-family configuration,
+        # for the CPU tests of the harness
+        program = cfg["program"]
+        base = (get_smoke if program.get("smoke") else get_arch)(
+            program["arch"])
+    period = base.groups[0][0]
+    p, layers = len(period), run["num_hidden_layers"]
+    if layers % p:
+        raise ValueError(f"{name}: num_hidden_layers {layers} is not a whole "
+                         f"number of the program's period of {p} blocks")
+    arch = dataclasses.replace(base, name=name, vocab_size=run["vocab_size"],
+                               groups=pattern_groups(period, layers))
+
     held = {
         "hidden_size": arch.d_model, "num_attention_heads": arch.n_heads,
         "num_key_value_heads": arch.n_kv_heads,
-        "head_dim": arch.resolved_head_dim, "intermediate_size": arch.d_ff,
-        "rope_theta": spec.rope_base,
+        "head_dim": arch.resolved_head_dim,
         "tie_word_embeddings": arch.tie_embeddings,
         "param_dtype": arch.param_dtype,
     }
-    wrong = {k: (v, run[k]) for k, v in held.items() if v != run[k]}
-    if wrong or arch.qkv_bias or arch.n_layers != run["num_hidden_layers"]:
-        raise ValueError(f"{cfg['name']}: program differs from the file: {wrong}")
+    given = {k: run[k] for k in held}
+    held["attention_bias"] = arch.qkv_bias
+    given["attention_bias"] = run.get("attention_bias", False)
+    kind_of = {Mixer.LOCAL_ATTN: model.SLIDING, Mixer.GLOBAL_ATTN: model.FULL}
+    held["layer_types"] = [kind_of.get(s.mixer, s.mixer.value)
+                           for s in period * (layers // p)]
+    given["layer_types"] = kinds = model.layer_kinds(run)
+    if kinds == held["layer_types"] and model.period(run) != p:
+        held["layer_types"] = f"a superblock of {p} layers"
+        given["layer_types"] = f"a period of {model.period(run)} layers"
+    windows = [s.window for s in period if s.mixer is Mixer.LOCAL_ATTN]
+    if windows:
+        held["sliding_window"] = windows
+        given["sliding_window"] = [run.get("sliding_window")] * len(windows)
+    rope = "rope_parameters" if "rope_parameters" in run else "rope_theta"
+    held[rope] = [s.rope_base for s in period]
+    try:
+        given[rope] = [model.rope_theta(run, k) for k in kinds[:p]]
+    except (KeyError, ValueError) as e:
+        given[rope] = f"not built: {e}"
+    if "mlp_layer_types" in run:
+        held["mlp_layer_types"] = ["sparse" if s.ff is FF.MOE else "dense"
+                                   for s in period * (layers // p)]
+        given["mlp_layer_types"] = run["mlp_layer_types"]
+    if any(s.ff not in (FF.MOE, FF.NONE) for s in period):
+        held["intermediate_size"] = arch.d_ff
+        given["intermediate_size"] = run.get("intermediate_size")
+    moe = [i for i, s in enumerate(period) if s.ff is FF.MOE]
+    if moe:
+        # the expert counts as the program's tree holds them
+        ff = LM(arch).param_defs()["groups"][0][f"b{moe[0]}"]["ff"]
+        experts = {
+            "num_experts": ff["w_gate"].shape[1],
+            "num_experts_total": ff["router"].shape[-1],
+            "moe_intermediate_size": ff["w_gate"].shape[-1],
+            "num_experts_per_tok": arch.moe.top_k,
+            "norm_topk_prob": PROGRAM_NORM_TOPK_PROB,
+            "router_aux_loss_coef": PROGRAM_AUX_COEF,
+        }
+        held.update(experts)
+        defaults = {"norm_topk_prob": True, "router_aux_loss_coef": 0.0}
+        given.update({k: run.get(k, defaults.get(k)) for k in experts})
+        # the reference computes every assignment; the program keeps
+        # ceil(n k cf / E) slots an expert for n tokens, room for all n at
+        # any n only where k cf >= E
+        m = arch.moe
+        held["dropless"] = m.top_k * m.capacity_factor >= m.n_experts
+        given["dropless"] = True
+    wrong = {k: (v, given[k]) for k, v in held.items() if v != given[k]}
+    if wrong:
+        raise ValueError(f"{name}: program differs from the file "
+                         f"(key: (program, file)): {wrong}")
+
+    paths, _ = jax.tree_util.tree_flatten_with_path(
+        LM(arch).param_defs(), is_leaf=pdefs.is_def)
+    theirs = [(jax.tree_util.keystr(k), d.shape, jnp.dtype(d.dtype))
+              for k, d in paths]
+    flat, _ = model._leaves(model.layout(run, blocks))
+    stores = model.leaf_stores(run, blocks, jnp.dtype(run["param_dtype"]))
+    mine = list(zip(model.leaf_names(run, blocks), (l.shape for l in flat),
+                    stores))
+    if theirs != mine:
+        diff = [(a, b) for a, b in zip(theirs, mine) if a != b]
+        raise ValueError(f"{name}: the program's parameter tree differs from "
+                         f"the reference's (program, reference): "
+                         f"{diff[:3] or (len(theirs), len(mine))}")
     return arch
 
 
@@ -342,20 +440,21 @@ def end_to_end(probe: Probe, cell: Cell, setup_s: float) -> dict:
 
 
 def context(cell: Cell, probe: Probe, reduced: dict, peak: dict) -> SimpleNamespace:
+    import jax.numpy as jnp
+
     from bench import flops
     from bench.reference import model
 
-    run = cell.run
-    flat, _ = model._leaves(model.layout(run, cell.cfg["blocks"]))
-    itemsize = {"bfloat16": 2, "float32": 4, "float16": 2}[run["param_dtype"]]
-    leaves = [(math.prod(l.shape), itemsize) for l in flat]
-    n_params = sum(n for n, _ in leaves)
+    run, blocks = cell.run, cell.cfg["blocks"]
+    flat, _ = model._leaves(model.layout(run, blocks))
+    stores = model.leaf_stores(run, blocks, jnp.dtype(run["param_dtype"]))
+    leaves = [(math.prod(l.shape), s.itemsize) for l, s in zip(flat, stores)]
     work = load_module(BENCH / "work" / f"{cell.wl['mode']}.py")
     return SimpleNamespace(
         cell=cell, trace=reduced, steps=len(probe.dispatch),
         window_s=probe.t_end - probe.t_start, input_s=probe.input_s,
-        flops_per_step=flops.train_step_flops(run, n_params, cell.rows,
-                                              cell.wl["seq"]),
+        flops_per_step=flops.train_step_flops(
+            run, flops.active_params(run, flat), cell.rows, cell.wl["seq"]),
         update_bytes_per_step=work.update_bytes(leaves, cell.wl["workers"]),
         peak=peak)
 

@@ -4,7 +4,8 @@ import jax
 import jax.numpy as jnp
 
 
-def defs(d: int, f: int) -> dict:
+def defs(run: dict) -> dict:
+    d, f = run["hidden_size"], run["intermediate_size"]
     return {"b_down": ((d,), "zeros"), "b_up": ((f,), "zeros"),
             "w_down": ((f, d), "normal"), "w_up": ((d, f), "normal")}
 
@@ -14,6 +15,7 @@ def _gelu(x: jax.Array) -> jax.Array:
     return 0.5 * x * (1.0 + jnp.tanh(c * (x + 0.044715 * x ** 3)))
 
 
-def apply(p: dict, x: jax.Array, num) -> jax.Array:
+def apply(p: dict, x: jax.Array, num, run: dict) -> tuple[jax.Array, float]:
+    """(output, auxiliary loss term: none)."""
     h = _gelu(num.mm(x, p["w_up"]) + p["b_up"].astype(jnp.float32))
-    return num.mm(h, p["w_down"]) + p["b_down"].astype(jnp.float32)
+    return num.mm(h, p["w_down"]) + p["b_down"].astype(jnp.float32), 0.0

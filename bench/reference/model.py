@@ -2,11 +2,15 @@
 
 A configuration file (``bench/configs/<name>.json``) gives the sizes under
 ``run`` and the block kinds under ``blocks``; this module builds the model
-from those alone.  The parameter tree has the layout and leaf order of the
-published model as the program stores it (a stacked layer axis, sorted
-names), so the weights drawn from a seed are the same draw: the seed's key
-is split into one key per leaf in that order, and a weight matrix is a
-standard normal scaled by 1/sqrt(its second-to-last dimension).
+from those alone.  The layers repeat a period of positions: the smallest
+period of the file's ``layer_types`` (one position where it gives none).
+The parameter tree has the layout and leaf order of the published model
+as the program stores it (one stacked entry ``b<i>`` per position of the
+period, with a leading axis over its repeats; sorted names), so the
+weights drawn from a seed are the same draw: the seed's key is split into
+one key per leaf in that order, and a weight matrix is a standard normal
+scaled by 1/sqrt(its second-to-last dimension).  A leaf is stored in the
+configuration's parameter dtype unless its kind states its own.
 
 ``readings`` follows the first steps of a training run and returns what the
 benchmark compares: each step's loss, each leaf's clipped first gradient
@@ -16,51 +20,110 @@ norm, and each leaf's change after the last step.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.reference import tied_embedding, update
+from bench.reference import update
 
 PyTree = Any
+SLIDING, FULL = "sliding_attention", "full_attention"
 
 
 @dataclasses.dataclass(frozen=True)
 class Leaf:
     shape: tuple
     init: str  # normal | zeros | ones
+    dtype: Optional[str] = None  # None: the configuration's parameter dtype
+    expert: bool = False  # one routed expert's weights, stacked by expert
 
 
 def _kind(name: str):
     return importlib.import_module(f"bench.reference.{name}")
 
 
+def embedding(run: dict):
+    return _kind("tied_embedding" if run.get("tie_word_embeddings", True)
+                 else "untied_embedding")
+
+
+def layer_kinds(run: dict) -> list[str]:
+    """Each layer's attention kind: the file's ``layer_types``; without
+    them, every layer slides where the file gives a ``sliding_window``,
+    else none does."""
+    n = run["num_hidden_layers"]
+    if "layer_types" in run:
+        kinds = list(run["layer_types"])
+        if len(kinds) != n or not set(kinds) <= {SLIDING, FULL}:
+            raise ValueError(f"layer_types: {n} of {SLIDING!r} or {FULL!r} "
+                             f"expected, got {kinds}")
+        return kinds
+    return [SLIDING if run.get("sliding_window") else FULL] * n
+
+
+def period(run: dict) -> int:
+    """The smallest number of layers whose kinds repeat through the stack."""
+    kinds = layer_kinds(run)
+    n = len(kinds)
+    return next(p for p in range(1, n + 1)
+                if n % p == 0 and kinds == kinds[:p] * (n // p))
+
+
+def rope_theta(run: dict, kind: str) -> float:
+    """The RoPE base of a layer kind: ``rope_parameters`` (one entry, or one
+    per layer kind) where the file gives them, else ``rope_theta``."""
+    params = run.get("rope_parameters")
+    if params is None:
+        return run["rope_theta"]
+    entry = params.get(kind, params)
+    if entry.get("rope_type", "default") != "default":
+        raise ValueError(f"rope_parameters: {kind} has rope_type "
+                         f"{entry['rope_type']!r}; only 'default' is built")
+    return entry["rope_theta"]
+
+
+def positions(run: dict) -> list[dict]:
+    """Per position of the period: the mixer's window and RoPE base."""
+    kinds = layer_kinds(run)[: period(run)]
+    return [{"window": run["sliding_window"] if k == SLIDING else None,
+             "rope_base": rope_theta(run, k)} for k in kinds]
+
+
+def _leaf(spec) -> Leaf:
+    return spec if isinstance(spec, Leaf) else Leaf(*spec)
+
+
 def layout(run: dict, blocks: dict) -> PyTree:
-    d, f = run["hidden_size"], run["intermediate_size"]
-    L = run["num_hidden_layers"]
+    d = run["hidden_size"]
+    reps = run["num_hidden_layers"] // period(run)
     norm = _kind(blocks["norm"])
     block = {
-        "ff": _kind(blocks["ff"]).defs(d, f),
-        "mixer": _kind(blocks["mixer"]).defs(
-            d, run["num_attention_heads"], run["num_key_value_heads"],
-            run["head_dim"]),
+        "ff": _kind(blocks["ff"]).defs(run),
+        "mixer": _kind(blocks["mixer"]).defs(run),
         "norm1": norm.defs(d),
         "norm2": norm.defs(d),
     }
-    stacked = {part: {k: Leaf((L,) + shape, init)
-                      for k, (shape, init) in leaves.items()}
+    stack = lambda l: dataclasses.replace(l, shape=(reps,) + l.shape)
+    stacked = {part: {k: stack(_leaf(s)) for k, s in leaves.items()}
                for part, leaves in block.items()}
-    flat = lambda t: {k: Leaf(shape, init) for k, (shape, init) in t.items()}
-    return {"embed": flat(tied_embedding.defs(run["vocab_size"], d)),
+    flat = lambda t: {k: _leaf(s) for k, s in t.items()}
+    return {"embed": flat(embedding(run).defs(run["vocab_size"], d)),
             "final_norm": flat(norm.defs(d)),
-            "groups": [{"b0": stacked}]}
+            "groups": [{f"b{i}": stacked for i in range(period(run))}]}
 
 
 def _leaves(tree):
     return jax.tree.flatten(tree, is_leaf=lambda x: isinstance(x, Leaf))
+
+
+def leaf_stores(run: dict, blocks: dict, store) -> list:
+    """Each leaf's storage dtype: its own, or ``store``."""
+    return [jnp.dtype(l.dtype) if l.dtype else store
+            for l in _leaves(layout(run, blocks))[0]]
 
 
 def _draw(leaf: Leaf, key) -> jax.Array:
@@ -74,14 +137,16 @@ def _draw(leaf: Leaf, key) -> jax.Array:
 
 
 def init(run: dict, blocks: dict, seed: int, store) -> PyTree:
-    """The weights drawn from ``seed``, stored in ``store``, in one call."""
+    """The weights drawn from ``seed``, stored as ``leaf_stores`` says, in
+    one call."""
     flat, tree = _leaves(layout(run, blocks))
+    stores = leaf_stores(run, blocks, store)
 
     @jax.jit
     def make(key):
         keys = jax.random.split(key, len(flat))
-        return tree.unflatten([_draw(l, k).astype(store)
-                               for l, k in zip(flat, keys)])
+        return tree.unflatten([_draw(l, k).astype(s)
+                               for l, k, s in zip(flat, keys, stores)])
 
     return make(jax.random.PRNGKey(seed))
 
@@ -93,29 +158,40 @@ def leaf_names(run: dict, blocks: dict) -> list[str]:
 
 
 def loss_fn(run: dict, blocks: dict, num, params: PyTree, tokens, labels):
+    """Mean cross-entropy plus ``router_aux_loss_coef`` times the sum of the
+    layers' auxiliary terms."""
     norm = _kind(blocks["norm"])
     ff = _kind(blocks["ff"])
     mixer = _kind(blocks["mixer"])
-    heads = dict(n_heads=run["num_attention_heads"],
-                 n_kv=run["num_key_value_heads"], head_dim=run["head_dim"],
-                 rope_base=run["rope_theta"])
+    emb = embedding(run)
 
-    @jax.checkpoint
-    def layer(p, x):
-        x = x + mixer.apply(p["mixer"], norm.apply(p["norm1"], x), num, **heads)
-        return x + ff.apply(p["ff"], norm.apply(p["norm2"], x), num)
+    def layer(p, x, *, where):
+        x = x + mixer.apply(p["mixer"], norm.apply(p["norm1"], x), num, run,
+                            **where)
+        y, aux = ff.apply(p["ff"], norm.apply(p["norm2"], x), num, run)
+        return x + y, aux
 
-    x = tied_embedding.embed(params["embed"], tokens, num)
-    stack = params["groups"][0]["b0"]
-    for i in range(run["num_hidden_layers"]):
-        x = layer(jax.tree.map(lambda a: a[i], stack), x)
+    layers = [jax.checkpoint(functools.partial(layer, where=w))
+              for w in positions(run)]
+    x = emb.embed(params["embed"], tokens, num)
+    aux = 0.0
+    group = params["groups"][0]
+    for r in range(run["num_hidden_layers"] // len(layers)):
+        for i, apply in enumerate(layers):
+            x, a = apply(jax.tree.map(lambda t: t[r], group[f"b{i}"]), x)
+            aux = aux + a
     h = norm.apply(params["final_norm"], x)
-    return tied_embedding.loss(params["embed"], h, labels, run["vocab_size"], num)
+    xent = emb.loss(params["embed"], h, labels, run["vocab_size"], num)
+    coef = run.get("router_aux_loss_coef", 0.0)
+    return xent + coef * aux if coef else xent
 
 
 def _grads(run, blocks, num, params, tokens, labels):
     """Loss and gradients, the gradients as the state is stored."""
-    wide = jax.tree.map(lambda p: p.astype(num.state_store), params)
+    stores = leaf_stores(run, blocks, num.state_store)
+    td = jax.tree.structure(params)
+    wide = td.unflatten([p.astype(s) for p, s in
+                         zip(jax.tree.leaves(params), stores)])
     return jax.value_and_grad(
         lambda p: loss_fn(run, blocks, num, p, tokens, labels))(wide)
 
@@ -126,23 +202,25 @@ def make_step(run: dict, blocks: dict, num, hyper: dict):
 
     ``hyper``: mode (bsp | isp | isp-pod), lr, clip, isp_v, pods, budget,
     block.  For isp-pod the batch rows are split evenly over the pods and
-    every state leaf has a leading pod axis.
+    every state leaf has a leading pod axis.  Each leaf's state is stored
+    as the leaf's own dtype where it states one, else in the numerics'.
     """
     mode, lr, clip = hyper["mode"], hyper["lr"], hyper["clip"]
     pods = hyper.get("pods", 1)
-    sstore, pstore = num.state_store, num.param_store
+    sstores = leaf_stores(run, blocks, num.state_store)
+    pstores = leaf_stores(run, blocks, num.param_store)
 
     def worker(params, m, v, tokens, labels, t):
         loss, g = _grads(run, blocks, num, params, tokens, labels)
         g = jax.tree.leaves(g)
         scale = update.clip_scale(g, clip) if clip else 1.0
         # the clipped gradient is a tree of the state's dtype
-        clipped = lambda x: (x.astype(jnp.float32) * scale).astype(
-            sstore).astype(jnp.float32)
-        norms = jnp.stack([jnp.sqrt(jnp.sum(jnp.square(clipped(x))))
-                           for x in g])
-        out = [update.adam(clipped(gl), ml, vl, t, lr, sstore)
-               for gl, ml, vl in zip(g, m, v)]
+        clipped = lambda x, s: (x.astype(jnp.float32) * scale).astype(
+            s).astype(jnp.float32)
+        norms = jnp.stack([jnp.sqrt(jnp.sum(jnp.square(clipped(x, s))))
+                           for x, s in zip(g, sstores)])
+        out = [update.adam(clipped(gl, s), ml, vl, t, lr, s)
+               for gl, ml, vl, s in zip(g, m, v, sstores)]
         return loss, norms, [o[0] for o in out], [o[1] for o in out], \
             [o[2] for o in out]
 
@@ -155,19 +233,19 @@ def make_step(run: dict, blocks: dict, num, hyper: dict):
         v_t = hyper.get("isp_v", 0.0) / jnp.sqrt(t + 1.0)
         if mode != "isp-pod":
             loss, norms, u, m, v = worker(params, m, v, tokens, labels, t)
-            u = [ul.astype(sstore) for ul in u]
+            u = [ul.astype(s) for ul, s in zip(u, sstores)]
             if mode == "bsp":
                 sent = u
             else:
                 acc = [(rl.astype(jnp.float32) + ul.astype(jnp.float32))
-                       .astype(sstore) for rl, ul in zip(r, u)]
+                       .astype(s) for rl, ul, s in zip(r, u, sstores)]
                 split = [update.significance_split(a.astype(jnp.float32),
                                                    xl.astype(jnp.float32), v_t)
                          for a, xl in zip(acc, x)]
                 sent = [s for s, _ in split]
-                r = [k.astype(sstore) for _, k in split]
-            x = [(xl.astype(jnp.float32) + s.astype(jnp.float32)).astype(pstore)
-                 for xl, s in zip(x, sent)]
+                r = [k.astype(s) for (_, k), s in zip(split, sstores)]
+            x = [(xl.astype(jnp.float32) + s.astype(jnp.float32)).astype(p)
+                 for xl, s, p in zip(x, sent, pstores)]
             return td.unflatten(x), (m, v, r), loss, norms
 
         rows = tokens.shape[0] // pods
@@ -184,6 +262,7 @@ def make_step(run: dict, blocks: dict, num, hyper: dict):
             v_p.append(vk)
         new_x, new_r = [], []
         for i, xl in enumerate(x):
+            sstore, pstore = sstores[i], pstores[i]
             xf = xl.astype(jnp.float32)
             combined = jnp.zeros(xl.shape, jnp.float32)
             res = []
@@ -214,6 +293,7 @@ def change_norms(run: dict, blocks: dict, store):
     """jitted (params, key) -> per-leaf norm of (params - the weights drawn
     from key); the weights are drawn again inside, leaf by leaf."""
     flat, _ = _leaves(layout(run, blocks))
+    stores = leaf_stores(run, blocks, store)
 
     @jax.jit
     def norms(params, key):
@@ -221,8 +301,9 @@ def change_norms(run: dict, blocks: dict, store):
         return jnp.stack([
             jnp.sqrt(jnp.sum(jnp.square(
                 p.astype(jnp.float32)
-                - _draw(l, k).astype(store).astype(jnp.float32))))
-            for p, l, k in zip(jax.tree.leaves(params), flat, keys)])
+                - _draw(l, k).astype(s).astype(jnp.float32))))
+            for p, l, k, s in zip(jax.tree.leaves(params), flat, keys,
+                                  stores)])
 
     return norms
 
@@ -237,9 +318,10 @@ def readings(run: dict, blocks: dict, num, hyper: dict, seed: int,
     """
     params = init(run, blocks, seed, num.param_store)
     leaves = jax.tree.leaves(params)
+    sstores = leaf_stores(run, blocks, num.state_store)
     pods = hyper.get("pods", 1) if hyper["mode"] == "isp-pod" else None
-    zeros = lambda: [jnp.zeros(((pods,) if pods else ()) + l.shape,
-                               num.state_store) for l in leaves]
+    zeros = lambda: [jnp.zeros(((pods,) if pods else ()) + l.shape, s)
+                     for l, s in zip(leaves, sstores)]
     state = (zeros(), zeros(), zeros() if hyper["mode"] != "bsp" else [])
     step = make_step(run, blocks, num, hyper)
     losses, grad = [], None

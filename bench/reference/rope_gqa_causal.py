@@ -3,9 +3,13 @@
 Projections are flat: W_q (d, H*dh), W_k and W_v (d, K*dh), W_o (H*dh, d).
 Query head h reads KV head h // (H / K).  RoPE rotates the two halves of
 each head (first half with second half) at frequencies base^(-i / (dh/2)).
-Queries are taken in chunks, each recomputed in the backward pass, so the
-(B, H, chunk, S) scores of one chunk are all that is held at a time.
+With a ``window`` w, query q attends to keys k with q - w < k <= q (a
+sliding window); without, to every k <= q.  Queries are taken in chunks,
+each recomputed in the backward pass, so the (B, H, chunk, S) scores of
+one chunk are all that is held at a time.
 """
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +17,9 @@ import jax.numpy as jnp
 SCORE_BYTES = 512 << 20  # scores of one query chunk, float32
 
 
-def defs(d: int, n_heads: int, n_kv: int, head_dim: int) -> dict:
+def defs(run: dict) -> dict:
+    d, n_heads = run["hidden_size"], run["num_attention_heads"]
+    n_kv, head_dim = run["num_key_value_heads"], run["head_dim"]
     return {"wk": ((d, n_kv * head_dim), "normal"),
             "wo": ((n_heads * head_dim, d), "normal"),
             "wq": ((d, n_heads * head_dim), "normal"),
@@ -38,8 +44,10 @@ def _query_chunk(b: int, s: int, heads: int) -> int:
     return c
 
 
-def apply(p: dict, x: jax.Array, num, *, n_heads: int, n_kv: int,
-          head_dim: int, rope_base: float) -> jax.Array:
+def apply(p: dict, x: jax.Array, num, run: dict, *, rope_base: float,
+          window: Optional[int] = None) -> jax.Array:
+    n_heads, n_kv = run["num_attention_heads"], run["num_key_value_heads"]
+    head_dim = run["head_dim"]
     b, s, _ = x.shape
     q = num.mm(x, p["wq"]).reshape(b, s, n_heads, head_dim)
     k = num.mm(x, p["wk"]).reshape(b, s, n_kv, head_dim)
@@ -59,6 +67,8 @@ def apply(p: dict, x: jax.Array, num, *, n_heads: int, n_kv: int,
         logits = num.einsum("bqhd,bkhd->bhqk", q_c, k) * scale
         q_pos = i * qc + jnp.arange(qc)
         allow = jnp.arange(s)[None, :] <= q_pos[:, None]
+        if window is not None:
+            allow = allow & (q_pos[:, None] - jnp.arange(s)[None, :] < window)
         logits = jnp.where(allow[None, None], logits, -jnp.inf)
         probs = jax.nn.softmax(logits, axis=-1)
         return num.einsum("bhqk,bkhd->bqhd", probs, v)

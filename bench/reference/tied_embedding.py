@@ -27,8 +27,13 @@ def embed(p: dict, tokens: jax.Array, num) -> jax.Array:
 
 def loss(p: dict, h: jax.Array, labels: jax.Array, vocab: int, num) -> jax.Array:
     """Mean cross-entropy of hidden states h (B, S, d) against labels."""
-    w = p["tok"]
-    vp, d = w.shape
+    return xent(p["tok"].T, h, labels, vocab, num)
+
+
+def xent(w: jax.Array, h: jax.Array, labels: jax.Array, vocab: int,
+         num) -> jax.Array:
+    """Mean cross-entropy of the logits h @ w (w: (d, padded vocab))."""
+    d, vp = w.shape
     rows = h.reshape(-1, d)
     ys = labels.reshape(-1)
     n = rows.shape[0]
@@ -40,7 +45,7 @@ def loss(p: dict, h: jax.Array, labels: jax.Array, vocab: int, num) -> jax.Array
     @jax.checkpoint
     def chunk(args):
         h_c, y_c = args
-        logits = num.mm(h_c, w.T) + bias
+        logits = num.mm(h_c, w) + bias
         lse = jax.nn.logsumexp(logits, axis=-1)
         lab = jnp.take_along_axis(logits, y_c[:, None], axis=-1)[:, 0]
         return jnp.sum(lse - lab)

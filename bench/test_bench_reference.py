@@ -12,8 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import compare, traffic
-from bench.reference import model, numerics
+from bench import compare, harness, traffic
+from bench.reference import model, moe_swiglu, numerics
 from repro import optim
 from repro.configs import get_smoke
 from repro.core.isp import ISPConfig
@@ -21,30 +21,63 @@ from repro.data.tokens import TokenPipeline
 from repro.dist.compression import CompressionConfig
 from repro.launch.train import lift_pod, make_pod_step, make_step
 from repro.models import params as pdefs
-from repro.models.config import uniform_groups
+from repro.models.config import BlockSpec, FF, Mixer, MoEConfig
+from repro.models.moe import capacity
 from repro.models.transformer import LM
 
-SMOKE = {
-    "starcoder2-7b": ({"mixer": "rope_gqa_causal", "ff": "gelu_ff",
-                       "norm": "layernorm"}, 3),
-    "phi4-mini-3.8b": ({"mixer": "rope_gqa_causal", "ff": "swiglu",
-                        "norm": "rmsnorm"}, 2),
-}
 SEED = 2**31 + 3
+SLIDING, FULL = model.SLIDING, model.FULL
+RUN = {"hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
+       "head_dim": 16, "intermediate_size": 128, "vocab_size": 300,
+       "rope_theta": 10000.0, "tie_word_embeddings": True,
+       "param_dtype": "bfloat16"}
+SWIGLU = {"mixer": "rope_gqa_causal", "ff": "swiglu", "norm": "rmsnorm"}
+# three sliding-window layers, then one full: the program's block kinds
+PATTERN = (BlockSpec(Mixer.LOCAL_ATTN, FF.SWIGLU, window=16),) * 3 + \
+    (BlockSpec(Mixer.GLOBAL_ATTN, FF.SWIGLU),)
+PATTERN_RUN = {"num_hidden_layers": 8, "layer_types": [SLIDING] * 3 + [FULL],
+               "sliding_window": 16}
+PATTERN_RUN["layer_types"] = PATTERN_RUN["layer_types"] * 2
+# 16 experts, top 2: expert_split is 1, so the program's experts are the
+# published ones; a capacity factor of E / k leaves room for every token
+EXPERTS = MoEConfig(n_experts=16, top_k=2, capacity_factor=8.0)
+EXPERT_RUN = {"num_hidden_layers": 2, "num_experts": 16,
+              "num_experts_per_tok": 2, "moe_intermediate_size": 128,
+              "router_aux_loss_coef": 0.01}
 
 
-def _pair(arch: str):
-    blocks, layers = SMOKE[arch]
-    base = get_smoke(arch)
-    (spec,), _ = base.groups[0]
-    cfg = dataclasses.replace(base, vocab_size=300,
-                              groups=uniform_groups(spec, layers))
-    run = {"hidden_size": cfg.d_model, "num_attention_heads": cfg.n_heads,
-           "num_key_value_heads": cfg.n_kv_heads,
-           "head_dim": cfg.resolved_head_dim, "intermediate_size": cfg.d_ff,
-           "num_hidden_layers": layers, "vocab_size": cfg.vocab_size,
-           "rope_theta": spec.rope_base, "param_dtype": cfg.param_dtype}
-    return LM(cfg), run, blocks
+def _base(name, **changes):
+    return lambda: dataclasses.replace(get_smoke(name), **changes)
+
+
+# name: (the program's architecture, blocks, the file's run, sequence)
+SMOKE = {
+    "starcoder2-7b": (_base("starcoder2-7b"),
+                      {"mixer": "rope_gqa_causal", "ff": "gelu_ff",
+                       "norm": "layernorm"}, {"num_hidden_layers": 3}, 32),
+    "phi4-mini-3.8b": (_base("phi4-mini-3.8b"), SWIGLU,
+                       {"num_hidden_layers": 2}, 32),
+    "pattern": (_base("phi4-mini-3.8b", groups=((PATTERN, 1),)), SWIGLU,
+                PATTERN_RUN, 64),
+    "pattern-untied": (_base("phi4-mini-3.8b", groups=((PATTERN, 1),),
+                             tie_embeddings=False), SWIGLU,
+                       dict(PATTERN_RUN, tie_word_embeddings=False), 64),
+    "experts": (_base("phi3.5-moe-42b-a6.6b", moe=EXPERTS),
+                dict(SWIGLU, ff="moe_swiglu"), EXPERT_RUN, 32),
+}
+
+
+def _pair(name: str):
+    base, blocks, run, _ = SMOKE[name]
+    cfg = {"name": f"smoke.{name}", "blocks": blocks, "run": dict(RUN, **run)}
+    return LM(harness.program_arch(cfg, base())), harness.sizes(cfg), blocks
+
+
+def _dropless(lm, tokens: int) -> None:
+    """No assignment can be dropped: every expert has room for every token
+    (a token chooses an expert at most once)."""
+    if lm.cfg.moe is not None:
+        assert capacity(tokens, lm.cfg) >= tokens
 
 
 def _batch(run, rows, seq, step=0):
@@ -55,10 +88,13 @@ def _batch(run, rows, seq, step=0):
 @pytest.mark.parametrize("arch", sorted(SMOKE))
 def test_layout_and_weights_are_the_programs(arch):
     lm, run, blocks = _pair(arch)
+    assert len(model.leaf_names(run, blocks)) == len(jax.tree.leaves(
+        lm.param_defs(), is_leaf=pdefs.is_def))
     paths, _ = jax.tree_util.tree_flatten_with_path(
         lm.param_defs(), is_leaf=pdefs.is_def)
     assert [jax.tree_util.keystr(p) for p, _ in paths] == \
         model.leaf_names(run, blocks)
+    # each leaf in its own dtype: the router in float32, the rest bfloat16
     ref = jax.tree.leaves(model.init(run, blocks, SEED, jnp.bfloat16))
     prog = jax.tree.leaves(lm.init(jax.random.PRNGKey(SEED)))
     assert sum(x.size for x in ref) == lm.n_params()
@@ -83,7 +119,11 @@ def test_traffic_is_the_programs(step):
 def test_loss_matches_train_loss(arch):
     lm, run, blocks = _pair(arch)
     params = lm.init(jax.random.PRNGKey(SEED))
-    toks, labels = _batch(run, 2, 40)
+    seq = SMOKE[arch][3] + 8
+    toks, labels = _batch(run, 2, seq)
+    _dropless(lm, 2 * seq)
+    # the program's loss is its cross-entropy plus 0.01 of the experts'
+    # load-balance term; the reference adds the file's coefficient
     prog, _ = lm.train_loss(params, {"tokens": toks, "labels": labels})
     ref = model.loss_fn(run, blocks, numerics.of("bfloat16"), params,
                         toks, labels)
@@ -91,7 +131,7 @@ def test_loss_matches_train_loss(arch):
     assert abs(float(prog) - float(ref)) / float(ref) < 2e-3
 
 
-def _first_steps(lm, run, blocks, mode, pods, steps=3):
+def _first_steps(lm, run, blocks, mode, pods, seq, steps=3):
     """Program readings of ``steps`` steps of make_step/make_pod_step."""
     opt = optim.make("adam", 3e-4)
     isp = ISPConfig(0.7) if mode != "bsp" else None
@@ -104,7 +144,7 @@ def _first_steps(lm, run, blocks, mode, pods, steps=3):
         fn = make_step(lm, opt, isp)
     losses, grad = [], None
     for s in range(steps):
-        toks, labels = _batch(run, pods * 2, 32, s)
+        toks, labels = _batch(run, pods * 2, seq, s)
         params, o, r, loss, _ = fn(params, o, r, {"tokens": toks,
                                                   "labels": labels})
         losses.append(float(loss))
@@ -120,14 +160,16 @@ def _first_steps(lm, run, blocks, mode, pods, steps=3):
 
 @pytest.mark.parametrize("arch,mode", [
     ("starcoder2-7b", "bsp"), ("starcoder2-7b", "isp"),
-    ("phi4-mini-3.8b", "isp-pod")])
+    ("phi4-mini-3.8b", "isp-pod"), ("pattern", "isp"), ("pattern", "isp-pod"),
+    ("pattern-untied", "isp"), ("experts", "isp"), ("experts", "isp-pod")])
 def test_steps_match_the_program(arch, mode):
     lm, run, blocks = _pair(arch)
-    pods = 4
-    prog = _first_steps(lm, run, blocks, mode, pods)
+    pods, seq = 4, SMOKE[arch][3]
+    _dropless(lm, pods * 2 * seq)
+    prog = _first_steps(lm, run, blocks, mode, pods, seq)
     hyper = {"mode": mode, "lr": 3e-4, "clip": 1.0, "isp_v": 0.7,
              "pods": pods, "budget": 0.01, "block": 128}
-    batches = [_batch(run, pods * 2, 32, s) for s in range(3)]
+    batches = [_batch(run, pods * 2, seq, s) for s in range(3)]
     ref = model.readings(run, blocks, numerics.of("bfloat16"), hyper, SEED,
                          batches)
     values, _ = compare.numbers(prog, ref)
@@ -137,3 +179,41 @@ def test_steps_match_the_program(arch, mode):
     # updates differently in the two, so only bsp and isp hold the change
     if mode != "isp-pod":
         assert values["change_gap"] < 0.05, values
+
+
+@pytest.mark.parametrize("shares", [2, 4])
+def test_held_experts_sum_to_the_whole_layer(shares):
+    """A layer that holds 16 / shares of the 16 experts gives their part of
+    the output; over the shares, with the attention counted once, the parts
+    add up to the uncut layer's output."""
+    from bench.reference import rmsnorm, rope_gqa_causal
+
+    blocks = dict(SWIGLU, ff="moe_swiglu")
+    run = harness.sizes({"run": dict(RUN, **EXPERT_RUN)})
+    num = numerics.of("float32")
+    params = model.init(run, blocks, SEED, jnp.float32)
+    p = jax.tree.map(lambda a: a[0], params["groups"][0]["b0"])
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, 64), jnp.float32)
+    h = x + rope_gqa_causal.apply(p["mixer"], rmsnorm.apply(p["norm1"], x),
+                                  num, run, rope_base=10000.0)
+    h2 = rmsnorm.apply(p["norm2"], h)
+    whole, aux = moe_swiglu.apply(p["ff"], h2, num, run)
+
+    held = 16 // shares
+    share = harness.sizes({
+        "run": dict(RUN, **dict(EXPERT_RUN, num_experts=held)),
+        "reduced": {"num_experts": 16}})
+    ff = model.layout(share, blocks)["groups"][0]["b0"]["ff"]
+    # the router keeps its full width; the experts are the share's alone
+    assert ff["router"].shape[-1] == 16 and ff["w_gate"].shape[1] == held
+    out = h
+    for s in range(shares):
+        sl = slice(s * held, (s + 1) * held)
+        part, aux_s = moe_swiglu.apply(
+            dict(p["ff"], **{k: p["ff"][k][sl] for k in
+                             ("w_down", "w_gate", "w_up")}),
+            h2, num, share, first=s * held)
+        assert float(jnp.linalg.norm(part)) > 0
+        np.testing.assert_allclose(aux_s, aux, rtol=1e-6)
+        out = out + part
+    np.testing.assert_allclose(out, h + whole, rtol=1e-5, atol=1e-5)

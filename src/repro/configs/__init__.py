@@ -1,4 +1,5 @@
-"""Architecture registry: the 10 assigned archs + the paper's own jobs.
+"""Architecture registry: the 10 assigned archs, mellum2 and its one-chip
+share, and the paper's own jobs.
 
 ``get_arch(name)`` returns the full-size ArchConfig; ``get_smoke(name)``
 returns a reduced same-family config for CPU smoke tests (small width/depth,
@@ -21,6 +22,7 @@ from repro.configs import (
     recurrentgemma_9b,
     xlstm_13b,
     paligemma_3b,
+    mellum2_12b,
 )
 
 _MODULES = {
@@ -34,6 +36,9 @@ _MODULES = {
     "recurrentgemma-9b": recurrentgemma_9b,
     "xlstm-1.3b": xlstm_13b,
     "paligemma-3b": paligemma_3b,
+    "mellum2-12b-a2.5b": mellum2_12b,
+    # one chip's share of a 4-chip expert-parallel deployment
+    "mellum2-12b-a2.5b.ep4": mellum2_12b.EP4,
 }
 
 ARCH_NAMES = tuple(_MODULES)

@@ -14,7 +14,9 @@ Three execution paths, chosen by sequence length and mode:
 * ``banded``  — sliding-window layers slice one static-width KV band per Q
   chunk (``dynamic_slice``), so SWA flops are O(Sq * window), not O(Sq^2).
 
-All softmax math is fp32 regardless of activation dtype.
+All softmax math is fp32 regardless of activation dtype.  Each core runs
+inside the named scope ``attn_core`` (``SCOPES``), so a profile can tell the
+attention itself from the projections around it.
 """
 
 from __future__ import annotations
@@ -34,6 +36,7 @@ from repro.models.params import ParamDef
 PyTree = Any
 
 NEG_INF = -1e30
+SCOPES = ("attn_core",)
 _DEFAULT_CHUNK = 1024
 _DENSE_MAX_SEQ = 2048  # dense path threshold
 
@@ -156,6 +159,7 @@ def _mask(
 # ---- cores ---------------------------------------------------------------------
 
 
+@jax.named_scope(SCOPES[0])
 def _dense_core(q, kv_k, kv_v, mask) -> jax.Array:
     """q (B,Sq,K,G,Dh), k/v (B,Skv,K,Dh), mask (Sq,Skv) -> (B,Sq,K,G,Dh)."""
     dh = q.shape[-1]
@@ -170,9 +174,13 @@ def _dense_core(q, kv_k, kv_v, mask) -> jax.Array:
 
 
 def _online_update(carry, logits, v_chunk):
-    """Online-softmax accumulation. carry = (m, l, acc)."""
+    """Online-softmax accumulation. carry = (m, l, acc).
+
+    The output does not depend on the running max, so no gradient is taken
+    through it: the backward pass then keeps no copy of the logits for the
+    max, only the probabilities."""
     m, l, acc = carry
-    m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
+    m_new = jax.lax.stop_gradient(jnp.maximum(m, jnp.max(logits, axis=-1)))
     correction = jnp.exp(m - m_new)
     p = jnp.exp(logits - m_new[..., None])
     l_new = l * correction + jnp.sum(p, axis=-1)
@@ -182,6 +190,7 @@ def _online_update(carry, logits, v_chunk):
     return m_new, l_new, acc_new
 
 
+@jax.named_scope(SCOPES[0])
 def _chunked_core(
     q,  # (B, Sq, K, G, Dh)
     kv_k,
@@ -238,6 +247,7 @@ def _chunked_core(
     return out.swapaxes(0, 1).reshape(b, sq, kh, g, dh)
 
 
+@jax.named_scope(SCOPES[0])
 def _kv_chunked_core(
     q,  # (B, Sq, K, G, Dh)
     kv_k,
@@ -297,6 +307,7 @@ def _kv_chunked_core(
     return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
 
 
+@jax.named_scope(SCOPES[0])
 def _banded_core(
     q,  # (B, Sq, K, G, Dh)
     kv_k,

@@ -49,9 +49,11 @@ class BlockSpec:
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int  # the router's width
     top_k: int = 2
     capacity_factor: float = 1.25
+    # experts held here, 0 .. n_held - 1 (None: all); the held path only
+    n_held: Optional[int] = None
 
 
 @dataclasses.dataclass(frozen=True)

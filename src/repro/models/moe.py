@@ -1,25 +1,44 @@
-"""Mixture-of-Experts FF with top-k routing and capacity-based dispatch.
+"""Mixture-of-Experts SwiGLU feed-forward with top-k routing.
 
-Dispatch is the cumsum/scatter formulation (no sort): for each (token, k)
-assignment we compute the token's position within its expert's capacity
-buffer via a cumulative sum over one-hot expert indicators, drop overflow,
-scatter into an (G, E, C, d) buffer, run the expert FFs as one grouped
-einsum, and gather back with the softmax gate weights. FLOPs are therefore
-O(top_k * capacity_factor * N * d * f) — the *active*-expert cost, not the
-all-experts dense cost, which keeps the roofline compute term honest
-(DESIGN.md §4: MoE is the paper's sparse-gradient regime analogue).
+Routing is the same in both paths: a float32 router over all ``n_experts``,
+a softmax, the ``top_k`` largest probabilities divided by their sum, and the
+Switch load-balance term over every token and every expert.  Which path a
+configuration takes is read from its ``MoEConfig``:
 
-Sharding: dispatch is GROUP-LOCAL. Tokens are reshaped into G groups, one
-per data-parallel shard (policy.moe_groups == product of batch-axis sizes),
-so capacity buffers shard over the batch axes and the (group -> expert)
-exchange lowers to the all-to-all GSPMD materializes at the expert-parallel
-boundary. A single global capacity buffer would be a (E, n*cap/E, d) scatter
-target whose sharding GSPMD cannot infer — group-locality is what keeps the
-MoE memory footprint per-chip O(local_tokens * d) at mixtral scale.
+* held (``dropless(cfg)``: ``top_k * capacity_factor >= n_experts``).  The
+  layer holds experts ``0 .. held - 1`` of the ``n_experts`` it routes over
+  (``MoEConfig.n_held``, default all of them) and gives their part of the
+  output alone; assignments to absent experts add nothing, and none is
+  dropped under any routing.  The assignments are sorted by expert into a
+  static buffer of ``N * min(top_k, held)`` rows, room for every one that
+  can land here, and the three SwiGLU matmuls run as grouped matmuls (the
+  Pallas ``megablox`` kernel) that visit only the rows the held experts
+  were given, up to one row tile per expert.  Leaves: ``router`` (d, E)
+  float32, ``w_gate`` / ``w_up`` (held, d, f), ``w_down`` (held, f, d).
+* capacity (otherwise: mixtral, phi3.5).  Dispatch is the cumsum/scatter
+  formulation (no sort): for each (token, k) assignment we compute the
+  token's position within its expert's capacity buffer via a cumulative sum
+  over one-hot expert indicators, drop overflow, scatter into an (G, E, C, d)
+  buffer, run the expert FFs as one grouped einsum, and gather back with the
+  gate weights. FLOPs are O(top_k * capacity_factor * N * d * f) — the
+  active-expert cost, not the all-experts dense cost.
 
-Expert placement: experts are expert-parallel over 'model' when the expert
-count divides the axis (phi3.5: 16e); otherwise each expert is tensor-sliced
-over (data, model) (mixtral: 8e < 16) — see moe_defs.
+Sharding (capacity path): dispatch is GROUP-LOCAL. Tokens are reshaped into
+G groups, one per data-parallel shard (policy.moe_groups == product of
+batch-axis sizes), so capacity buffers shard over the batch axes and the
+(group -> expert) exchange lowers to the all-to-all GSPMD materializes at
+the expert-parallel boundary. A single global capacity buffer would be a
+(E, n*cap/E, d) scatter target whose sharding GSPMD cannot infer —
+group-locality is what keeps the MoE memory footprint per-chip
+O(local_tokens * d) at mixtral scale.
+
+Expert placement (capacity path): experts are expert-parallel over 'model'
+when the expert count divides the axis (phi3.5: 16e); otherwise each expert
+is tensor-sliced over (data, model) (mixtral: 8e < 16) — see moe_defs.
+
+The held path names its parts with ``jax.named_scope`` (``SCOPES``): the
+routing, the dispatch into the sorted buffer, the experts' matmuls and the
+combine, so that a profile can split the layer's device time.
 """
 
 from __future__ import annotations
@@ -29,6 +48,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
 from repro.models.config import ArchConfig
 from repro.models.params import ParamDef
@@ -37,6 +57,18 @@ PyTree = Any
 
 
 EP_TARGET = 16  # 'model' axis size of the production meshes
+SCOPES = ("moe_route", "moe_dispatch", "moe_experts", "moe_combine")
+
+
+def dropless(cfg: ArchConfig) -> bool:
+    """Room for every assignment: the held path."""
+    m = cfg.moe
+    return m.top_k * m.capacity_factor >= m.n_experts
+
+
+def held_experts(cfg: ArchConfig) -> int:
+    m = cfg.moe
+    return m.n_held or m.n_experts
 
 
 def expert_split(cfg: ArchConfig) -> int:
@@ -59,12 +91,19 @@ def moe_defs(cfg: ArchConfig) -> PyTree:
     the unsplit output summed over slices). This keeps the (G, E', C, d)
     dispatch buffer shardable over 'model' for every expert count — a
     replicated buffer would force a full all-reduce of the buffer at the
-    scatter (measured 101s collective term for mixtral before this fix;
-    EXPERIMENTS.md §Perf)."""
+    scatter."""
     assert cfg.moe is not None
     e, d, f = cfg.moe.n_experts, cfg.d_model, cfg.d_ff
-    s = expert_split(cfg)
     dt = jnp.dtype(cfg.param_dtype)
+    if dropless(cfg):
+        held = held_experts(cfg)
+        return {
+            "router": ParamDef((d, e), jnp.float32, (None, None)),
+            "w_gate": ParamDef((held, d, f), dt, ("model", "data", None)),
+            "w_up": ParamDef((held, d, f), dt, ("model", "data", None)),
+            "w_down": ParamDef((held, f, d), dt, ("model", None, "data")),
+        }
+    s = expert_split(cfg)
     return {
         "router": ParamDef((d, e), jnp.float32, (None, None)),
         "w_gate": ParamDef((e * s, d, f // s), dt, ("model", "data", None)),
@@ -87,6 +126,8 @@ def moe_apply(
     aux_loss is the standard load-balance term (mean gate prob * token
     density per expert, scaled by E) — returned so the train loop can add it.
     """
+    if dropless(cfg):
+        return held_apply(cfg, p, x)
     m = cfg.moe
     b, s, d = x.shape
     n = b * s
@@ -167,7 +208,7 @@ def moe_apply(
         RESIDENT weight slices (E'/tp experts per chip — no per-layer
         weight gather), and a second all_to_all returns the outputs. This
         moves tokens (~0.2 GB/chip/layer) instead of expert weights
-        (~4.8 GB/layer for mixtral) — EXPERIMENTS.md §Perf iteration 2.
+        (~4.8 GB/layer for mixtral).
         """
         contrib = jnp.where(kv_[..., None], xt_[:, tok_idx], 0).astype(
             x.dtype
@@ -246,6 +287,125 @@ def moe_apply(
             xt, flat_ev, safe_slot, keep_v, w_assign, w_gate, w_up, w_down
         )
     return out.reshape(b, s, d), aux.astype(jnp.float32)
+
+
+# ---- the held path -------------------------------------------------------------
+
+
+def _route(cfg: ArchConfig, router: jax.Array, rows: jax.Array):
+    """(gates (N, k) renormalised, expert ids (N, k), aux), all in float32."""
+    m = cfg.moe
+    logits = jnp.matmul(rows.astype(jnp.float32), router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, ids = jax.lax.top_k(probs, m.top_k)
+    gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    density = jnp.mean(
+        jnp.sum(jax.nn.one_hot(ids, m.n_experts, dtype=jnp.float32), axis=1),
+        axis=0)
+    aux = m.n_experts * jnp.sum(density / m.top_k * jnp.mean(probs, axis=0))
+    return gates, ids, aux
+
+
+def _tile(dim: int, most: int, step: int) -> int:
+    """The largest multiple of ``step`` up to ``most`` that divides ``dim``;
+    the whole of ``dim`` where none does."""
+    for t in range(most - most % step, 0, -step):
+        if dim % t == 0:
+            return t
+    return dim
+
+
+def _tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
+    """Row, contraction and output tiles of one grouped matmul, forward or
+    backward: whole tiles of the widths, in the kernel's default VMEM."""
+    return _tile(m, 512, 8), _tile(k, 1152, 128), _tile(n, 1152, 128)
+
+
+def _grouped_mm(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array) -> jax.Array:
+    """Rows ``sizes[0]`` first of lhs times rhs[0], and so on; the rows past
+    ``sum(sizes)`` are neither read nor written (they hold anything)."""
+    return megablox.gmm(lhs, rhs, sizes, lhs.dtype, _tiling, None, None, False,
+                        jax.default_backend() != "tpu")
+
+
+@jax.custom_vjp
+def _dispatch(rows, src, pos, valid):
+    """Row i of the buffer is token ``src[i]``'s row.  Its transpose gathers
+    too: a token's gradient is the sum of its held assignments' rows
+    (``pos``, ``valid``: where each (token, k) assignment sits, and whether
+    its expert is held), so no scatter runs in either direction."""
+    return rows[src]
+
+
+def _dispatch_fwd(rows, src, pos, valid):
+    return rows[src], (pos, valid)
+
+
+def _dispatch_bwd(res, g):
+    pos, valid = res
+    back = jnp.where(valid[..., None], g[jnp.minimum(pos, g.shape[0] - 1)], 0)
+    return (jnp.sum(back.astype(jnp.float32), axis=1).astype(g.dtype), None,
+            None, None)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, pos, valid, src_a, live):
+    """(N, k, d): each assignment's row of the buffer, 0 where its expert is
+    absent.  The transpose takes buffer row i from assignment ``src_a[i]``
+    where ``live[i]``."""
+    return jnp.where(valid[..., None], ys[jnp.minimum(pos, ys.shape[0] - 1)], 0)
+
+
+def _combine_fwd(ys, pos, valid, src_a, live):
+    return _combine(ys, pos, valid, src_a, live), (src_a, live)
+
+
+def _combine_bwd(res, g):
+    src_a, live = res
+    flat = g.reshape(-1, g.shape[-1])
+    return jnp.where(live[:, None], flat[src_a], 0), None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def held_apply(cfg: ArchConfig, p: PyTree, x: jax.Array):
+    """The held experts' part of the layer, dropless: x (B, S, d) ->
+    (out, aux_loss)."""
+    m = cfg.moe
+    b, s, d = x.shape
+    n, k = b * s, m.top_k
+    held = p["w_gate"].shape[0]
+    rows = x.reshape(n, d)
+    with jax.named_scope("moe_route"):
+        gates, ids, aux = _route(cfg, p["router"], rows)
+    with jax.named_scope("moe_dispatch"):
+        # the (token, k) assignments in token order; sorted by expert, the
+        # held experts' come first and those of absent experts last
+        key = jnp.where(ids < held, ids, held).reshape(n * k)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        sizes = jnp.sum(jax.nn.one_hot(key, held, dtype=jnp.int32), axis=0)
+        # a token gives a held expert at most one of its k assignments
+        cap = -(-n * min(k, held) // 8) * 8
+        src_a = jnp.pad(order, (0, max(cap - n * k, 0)))[:cap]
+        live = jnp.arange(cap) < jnp.sum(sizes)
+        pos = jnp.zeros(n * k, jnp.int32).at[order].set(
+            jnp.arange(n * k, dtype=jnp.int32), unique_indices=True)
+        pos, valid = pos.reshape(n, k), ids < held
+        xs = _dispatch(rows, src_a // k, pos, valid)
+    with jax.named_scope("moe_experts"):
+        g = _grouped_mm(xs, p["w_gate"].astype(x.dtype), sizes)
+        u = _grouped_mm(xs, p["w_up"].astype(x.dtype), sizes)
+        h = (jax.nn.silu(g) * u).astype(x.dtype)
+        ys = _grouped_mm(h, p["w_down"].astype(x.dtype), sizes)
+    with jax.named_scope("moe_combine"):
+        y = _combine(ys, pos, valid, src_a, live)
+        out = jnp.sum(y.astype(jnp.float32) * gates[..., None], axis=1)
+    return out.astype(x.dtype).reshape(b, s, d), aux
 
 
 def _axes_set(group_ax) -> set:

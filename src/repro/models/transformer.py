@@ -43,7 +43,7 @@ from repro.models.layers import (
     sinusoidal_positions,
     unembed_apply,
 )
-from repro.models.moe import moe_apply, moe_defs
+from repro.models.moe import held_experts, moe_apply, moe_defs
 from repro.models.rglru import rglru_apply, rglru_cache_defs, rglru_defs
 from repro.models.xlstm import (
     mlstm_apply,
@@ -121,14 +121,18 @@ class LM:
         total = self.n_params()
         if cfg.moe is None:
             return total
+        # a token's k assignments fall on the experts held here in the share
+        # held / n_experts (all of them where the layer holds every expert)
+        held = held_experts(cfg)
         moe_total = 0
         moe_active = 0
         for sb, reps in cfg.groups:
             for s in sb:
                 if s.ff is FF.MOE:
                     per_expert = 3 * cfg.d_model * cfg.d_ff
-                    moe_total += reps * cfg.moe.n_experts * per_expert
-                    moe_active += reps * cfg.moe.top_k * per_expert
+                    moe_total += reps * held * per_expert
+                    moe_active += (reps * cfg.moe.top_k * held * per_expert
+                                   // cfg.moe.n_experts)
         return total - moe_total + moe_active
 
     # ---- caches -----------------------------------------------------------------
@@ -234,18 +238,23 @@ class LM:
 
         pol = self.policy
 
-        @partial(jax.checkpoint, static_argnums=())
+        # each block is rematerialized in the backward pass, so the backward
+        # holds one block's activations at a time (four expert layers'
+        # dispatch buffers would not fit one chip at 8k)
+        @partial(jax.checkpoint, static_argnums=(0,))
+        def apply_block(spec, p, xc, c_in):
+            return self._apply_block(spec, p, xc, c_in, **kw)
+
         def superblock_fwd(xc, p_sb, c_sb):
-            """One superblock; rematerialized in the backward pass so the
-            scan saves only the (SP-sharded) residual-stream carry per rep —
-            O(depth * B*S*D / (dp*tp)) activation memory (DESIGN.md §5)."""
+            """One superblock; its blocks are rematerialized in the backward
+            pass so the scan saves only the (SP-sharded) residual-stream
+            carry per block — O(depth * B*S*D / (dp*tp)) activation memory
+            (DESIGN.md §5)."""
             aux_acc = jnp.zeros((), jnp.float32)
             new_caches = {}
             for i, spec in enumerate(superblock):
                 c_in = c_sb.get(f"b{i}") if has_cache else None
-                xc, aux, c_out = self._apply_block(
-                    spec, p_sb[f"b{i}"], xc, c_in, **kw
-                )
+                xc, aux, c_out = apply_block(spec, p_sb[f"b{i}"], xc, c_in)
                 aux_acc = aux_acc + aux
                 if c_out is not None and has_cache:
                     new_caches[f"b{i}"] = c_out

@@ -69,6 +69,10 @@ _EXPECT = {
                               V=256_000),
     "xlstm-1.3b": dict(L=48, d=2048, H=4, kv=4, ff=0, V=50_304),
     "paligemma-3b": dict(L=18, d=2048, H=8, kv=1, ff=16_384, V=257_216),
+    # ff: the experts' width
+    "mellum2-12b-a2.5b": dict(L=28, d=2304, H=32, kv=4, ff=896, V=98_304),
+    "mellum2-12b-a2.5b.ep4": dict(L=28, d=2304, H=32, kv=4, ff=896,
+                                  V=98_304),
 }
 
 
@@ -195,3 +199,34 @@ def test_param_counts_near_nameplate():
         lm = LM(get_arch(name))
         n = lm.n_params()
         assert abs(n - want) / want < tol, f"{name}: {n:,} vs {want:,}"
+
+
+@pytest.mark.parametrize("window", [None, 24])
+@pytest.mark.parametrize("core", ["chunked", "kv_chunked"])
+def test_online_softmax_cores_match_dense(core, window):
+    """The chunked cores (online softmax over KV chunks, no gradient through
+    the running max) give the dense core's output and gradients, causal and
+    windowed, with chunks small enough for several KV steps."""
+    from repro.models import attention as att
+
+    b, s, kh, g, dh, chunk = 2, 64, 2, 2, 16, 16
+    kq, kk, kv, kc = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(kq, (b, s, kh, g, dh), jnp.float32)
+    k = jax.random.normal(kk, (b, s, kh, dh), jnp.float32)
+    v = jax.random.normal(kv, (b, s, kh, dh), jnp.float32)
+    cot = jax.random.normal(kc, (b, s, kh, g, dh), jnp.float32)
+    pos = jnp.arange(s)
+    mask = att._mask(pos, pos, True, window, None)
+    fn = {"chunked": att._chunked_core, "kv_chunked": att._kv_chunked_core}[core]
+
+    def dense(q_, k_, v_):
+        return jnp.sum(att._dense_core(q_, k_, v_, mask) * cot)
+
+    def chunked(q_, k_, v_):
+        return jnp.sum(fn(q_, k_, v_, 0, True, window, None, chunk) * cot)
+
+    want, want_g = jax.value_and_grad(dense, argnums=(0, 1, 2))(q, k, v)
+    got, got_g = jax.value_and_grad(chunked, argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    for a, e in zip(got_g, want_g):
+        np.testing.assert_allclose(a, e, rtol=1e-4, atol=1e-5)

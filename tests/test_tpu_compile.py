@@ -124,6 +124,34 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_held_expert_layer_compiles_for_v5e(one_chip, monkeypatch):
+    """The Mellum2 cell's expert layer, forward and backward, at its shapes:
+    16 of 64 experts of width 896 at d 2304, 16,384 tokens.  Its grouped
+    matmuls lower to the TPU kernel in the tiles they run in."""
+    from repro.configs import get_arch
+    from repro.models.moe import held_apply, moe_defs
+    from repro.models import params as pdefs
+
+    # the layer picks the kernel or its interpreter by the backend it runs on
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_arch("mellum2-12b-a2.5b.ep4")
+    p = jax.tree.map(
+        lambda d: jax.ShapeDtypeStruct(d.shape, d.dtype, sharding=one_chip),
+        moe_defs(cfg), is_leaf=pdefs.is_def)
+    x = jax.ShapeDtypeStruct((2, 8192, 2304), jnp.bfloat16, sharding=one_chip)
+
+    def loss(p_, x_):
+        out, aux = held_apply(cfg, p_, x_)
+        return jnp.sum(out.astype(jnp.float32)) + aux
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, x).compile()
+    text = compiled.as_text()
+    # three forward and, for each, two backward grouped matmuls
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    mem = compiled.memory_analysis()
+    assert 0 < mem.temp_size_in_bytes < HBM_BYTES
+
+
 def test_lm100m_isp_step_fits_one_v5e(one_chip):
     from repro import optim
     from repro.core.isp import ISPConfig

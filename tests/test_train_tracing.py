@@ -35,11 +35,13 @@ MODE_PHASES = {
 # loop-invariant pieces (the RoPE angles, the causal mask, a where's
 # broadcast) are hoisted out of the layers' scan by JAX's partial
 # evaluation with no name stack; the pod step's loss mean is outside the
-# phases by design.
+# phases by design.  The mask's broadcast keeps the attention core's own
+# scope (``attention.SCOPES``) when it is hoisted.
 UNSCOPED_OK = {
     "jit(step_fn)/" + n for n in (
         "le", "sin", "cos", "pow", "mul", "iota", "neg", "div",
-        "convert_element_type", "jit(_where)/broadcast_in_dim", "reduce_sum")
+        "convert_element_type", "jit(_where)/broadcast_in_dim",
+        "attn_core/jit(_where)/broadcast_in_dim", "reduce_sum")
 }
 CHECKED = ("fusion", "dot", "convolution", "reduce", "sort")
 INSTR = re.compile(r"^\s*(ROOT )?%([\w.-]+) = .*? ([a-z][\w-]*)\((.*)$")

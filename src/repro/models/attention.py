@@ -1,22 +1,30 @@
 """Attention: GQA + RoPE + causal / sliding-window / prefix-LM / cross.
 
-Three execution paths, chosen by sequence length and mode:
+Execution paths (cores), chosen by ``pick_core`` from the backend, the
+sequence length, the mask kind and the partitioning:
 
+* ``flash``   — JAX's Pallas splash-attention kernel, forward and a
+  recomputing backward: the forward keeps only the output and each row's
+  log-sum-exp, the backward rebuilds each block's probabilities in VMEM, and
+  blocks the causal or window mask empties are skipped.  Train/prefill of
+  causal self-attention at long sequence on one TPU.
 * ``dense``   — single einsum + masked softmax. Decode (q_len == 1) and short
   sequences. Memory O(Sq*Skv).
 * ``chunked`` — outer ``lax.scan`` over Q chunks (rematerialized), inner scan
   over KV chunks with online-softmax accumulation: the XLA-level flash
-  attention. Memory O(chunk^2). Used for train/prefill at long seq.
+  attention. Memory O(chunk^2). Train/prefill at long seq off the TPU, or
+  where the flash core does not apply.
   NOTE: the inner scan visits all KV chunks and masks — causal upper-triangle
-  tiles are wasted flops in this XLA fallback (the Pallas kernel
-  ``repro.kernels.flash_attention`` skips them on real TPUs; see
-  EXPERIMENTS.md §Perf for the measured gap).
+  tiles are wasted flops in this XLA fallback.
+* ``kv_chunked`` — the same online softmax with q kept whole, for a
+  sequence-sharded q.
 * ``banded``  — sliding-window layers slice one static-width KV band per Q
   chunk (``dynamic_slice``), so SWA flops are O(Sq * window), not O(Sq^2).
 
-All softmax math is fp32 regardless of activation dtype.  Each core runs
-inside the named scope ``attn_core`` (``SCOPES``), so a profile can tell the
-attention itself from the projections around it.
+Softmax statistics are fp32 regardless of activation dtype (the flash core
+takes its matmul operands in the activation dtype, accumulating in fp32).
+Each core runs inside the named scope ``attn_core`` (``SCOPES``), so a
+profile can tell the attention itself from the projections around it.
 """
 
 from __future__ import annotations
@@ -27,6 +35,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 from jax.sharding import PartitionSpec as P
 
 from repro.models.config import ArchConfig, BlockSpec, Mixer
@@ -39,6 +48,7 @@ NEG_INF = -1e30
 SCOPES = ("attn_core",)
 _DEFAULT_CHUNK = 1024
 _DENSE_MAX_SEQ = 2048  # dense path threshold
+_LANES = 128  # the flash kernel's head dim and blocks are whole lane tiles
 
 
 def _divisor_chunk(s: int, chunk: int) -> int:
@@ -345,6 +355,90 @@ def _banded_core(
     return out.swapaxes(0, 1).reshape(b, sq, kh, g, dh)
 
 
+def _flash_block(s: int, window: Optional[int]) -> int:
+    """The flash kernel's one block size (q, kv, compute, forward and
+    backward), from the sequence and the window.  512 where a window is set:
+    a query block then visits 1.5 windows of keys, not 2 as at 1,024 (on a
+    TPU v5e, forward and backward of a 1,024 window at 2 x 8,192, 32 heads:
+    17.1 ms at 512, 19.1 at 1,024).  1,024 for a full causal layer, where
+    larger blocks keep the MXU fed (2 x 16,384, 24 heads: 107.0 ms at
+    1,024, 134.5 at 512).  At most the sequence."""
+    return min(s, 512 if window is not None else 1024)
+
+
+def _flash_kernel(s: int, heads: int, window: Optional[int]):
+    """The splash kernel for one example: causal, or causal within the window
+    (``_mask``'s ``qp - kp < window``, ``kp <= qp``).  The masks' block
+    tables are computed once per shape (the library caches them)."""
+    if window is None:
+        mask = splash.CausalMask((s, s))
+    else:
+        mask = splash.LocalMask((s, s), (window - 1, 0), offset=0)
+    blk = _flash_block(s, window)
+    blocks = splash.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=blk,
+        block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
+        block_q_dq=blk, block_kv_dq=blk)
+    return splash.make_splash_mha(
+        splash.MultiHeadMask([mask] * heads), block_sizes=blocks,
+        head_shards=1, q_seq_shards=1,
+        interpret=jax.default_backend() != "tpu")
+
+
+@jax.named_scope(SCOPES[0])
+def _flash_core(
+    q,  # (B, S, K, G, Dh)
+    kv_k,
+    kv_v,  # (B, S, K, Dh)
+    window: Optional[int],
+) -> jax.Array:
+    """Causal self-attention (windowed where ``window`` is set) through the
+    splash kernel, one example at a time.  Query heads are grouped KV-major,
+    head k*G + g reading KV head k, as the kernel's GQA expects.  The kernel
+    takes no scale, so q is scaled by 1/sqrt(Dh) first (in fp32, rounded
+    once to the activation dtype)."""
+    b, s, kh, g, dh = q.shape
+    scale = 1.0 / jnp.sqrt(dh).astype(jnp.float32)
+    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    qs = qs.reshape(b, s, kh * g, dh).transpose(0, 2, 1, 3)
+    out = jax.vmap(_flash_kernel(s, kh * g, window))(
+        qs, kv_k.transpose(0, 2, 1, 3), kv_v.transpose(0, 2, 1, 3))
+    return out.transpose(0, 2, 1, 3).reshape(b, s, kh, g, dh)
+
+
+def pick_core(
+    *,
+    backend: str,
+    s: int,
+    head_dim: int,
+    window: Optional[int],
+    causal: bool,
+    prefix_len: Optional[int],
+    seq_sharded: bool,
+    n_devices: int,
+) -> str:
+    """The core of one train/prefill self-attention call, from what the
+    call can observe: ``flash``, ``dense``, ``chunked``, ``kv_chunked`` or
+    ``banded``.  (Decode and cross-attention keep cores of their own.)
+
+    The flash kernel takes causal attention (full or windowed, no
+    prefix-LM) past the dense threshold, on a TPU, where the head dim and
+    the sequence are whole lane tiles and blocks, and where the operands
+    are not partitioned (a pallas_call on a sharded mesh would be
+    replicated).  Everything else keeps its XLA core."""
+    if (backend == "tpu" and s > _DENSE_MAX_SEQ and causal
+            and prefix_len is None and n_devices == 1
+            and head_dim % _LANES == 0 and s % _flash_block(s, window) == 0):
+        return "flash"
+    if window is not None and s > window:
+        return "banded"
+    if s <= _DENSE_MAX_SEQ:
+        return "dense"
+    if seq_sharded:
+        return "kv_chunked"
+    return "chunked"
+
+
 # ---- block application ------------------------------------------------------------
 
 
@@ -397,7 +491,7 @@ def attn_apply(
 
     Modes:
       * train/prefill: ``cache is None`` (train) or cache returned filled
-        (prefill): full-sequence x, chunked/banded cores.
+        (prefill): full-sequence x, the core ``pick_core`` names.
       * decode: ``decode_pos`` given, x is (B, 1, D), cache is read+updated.
       * cross: ``cross_kv`` is the encoder output (B, Senc, D); no cache
         mutation (cross KV is precomputed into the cache at prefill).
@@ -419,6 +513,12 @@ def attn_apply(
     # (Megatron layout — the seq constraint re-applies at the block output)
     q_seq_ax = None if policy.heads is not None else policy.seq
     q = policy.constrain(q, (policy.batch, q_seq_ax, policy.heads, None))
+    decode = decode_pos is not None and cache is not None
+    kind = None if decode or cross_kv is not None else pick_core(
+        backend=jax.default_backend(), s=s, head_dim=cfg.resolved_head_dim,
+        window=window, causal=causal, prefix_len=prefix_len,
+        seq_sharded=policy.heads is None and policy.seq is not None,
+        n_devices=1 if policy.mesh is None else policy.mesh.size)
     # Head-sharded execution (train/prefill): repeat K/V up to the full head
     # count so every attention einsum has the same head dim — GSPMD then
     # pad-shards H over 'model' uniformly. Without this, the (K, G) grouped
@@ -427,8 +527,10 @@ def attn_apply(
     # whole activations). KV-cache layouts keep the un-repeated GQA K.
     k_cache_src, v_cache_src = k, v
     n_kv_eff = n_kv
+    # the flash kernel reads each KV head for its group of query heads and
+    # runs unpartitioned: it takes K/V as they are
     if (policy.heads is not None and n_kv < cfg.n_heads
-            and decode_pos is None):
+            and decode_pos is None and kind != "flash"):
         g_rep = cfg.n_heads // n_kv
         k = jnp.repeat(k, g_rep, axis=2)
         v = jnp.repeat(v, g_rep, axis=2)
@@ -445,7 +547,7 @@ def attn_apply(
         return t.reshape(t.shape[0], t.shape[1], n_kv, cfg.resolved_head_dim)
 
     new_cache = cache
-    if decode_pos is not None and cache is not None:
+    if decode:
         # -- decode: write k/v at decode_pos (ring for local), dense core
         cache_len = cache["k"].shape[1]
         if window is not None:
@@ -500,14 +602,16 @@ def attn_apply(
                     cache["v"], _flat(v_w)[:, :cache_len], 0, axis=1
                 )
             new_cache = {"k": ck, "v": cv}
-        if window is not None and s > window:
+        if kind == "flash":
+            out = _flash_core(qg, k, v, window)
+        elif kind == "banded":
             out = _banded_core(qg, k, v, 0, window, chunk)
-        elif s <= _DENSE_MAX_SEQ:
+        elif kind == "dense":
             allow = _mask(
                 jnp.arange(s), jnp.arange(s), causal, window, prefix_len
             )
             out = _dense_core(qg, k, v, allow)
-        elif policy.heads is None and policy.seq is not None:
+        elif kind == "kv_chunked":
             # q is sequence-sharded and heads cannot take the 'model' axis:
             # the q-chunk scan would slice a sharded dim (replication) —
             # keep q whole and stream KV chunks instead

@@ -202,14 +202,19 @@ def test_param_counts_near_nameplate():
 
 
 @pytest.mark.parametrize("window", [None, 24])
-@pytest.mark.parametrize("core", ["chunked", "kv_chunked"])
-def test_online_softmax_cores_match_dense(core, window):
+@pytest.mark.parametrize("core", ["chunked", "kv_chunked", "flash", "flash-g4"])
+def test_online_softmax_cores_match_dense(core, window, monkeypatch):
     """The chunked cores (online softmax over KV chunks, no gradient through
-    the running max) give the dense core's output and gradients, causal and
-    windowed, with chunks small enough for several KV steps."""
+    the running max) and the flash kernel (interpreted here, blocks of 128
+    so that a row spans three) give the dense core's output and gradients,
+    causal and windowed, with chunks small enough for several KV steps."""
     from repro.models import attention as att
 
     b, s, kh, g, dh, chunk = 2, 64, 2, 2, 16, 16
+    if core.startswith("flash"):
+        # the kernel's head dim and blocks are whole lane tiles
+        monkeypatch.setattr(att, "_flash_block", lambda s_, w_: 128)
+        s, dh, g = 384, 128, (4 if core == "flash-g4" else 2)
     kq, kk, kv, kc = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(kq, (b, s, kh, g, dh), jnp.float32)
     k = jax.random.normal(kk, (b, s, kh, dh), jnp.float32)
@@ -217,16 +222,104 @@ def test_online_softmax_cores_match_dense(core, window):
     cot = jax.random.normal(kc, (b, s, kh, g, dh), jnp.float32)
     pos = jnp.arange(s)
     mask = att._mask(pos, pos, True, window, None)
-    fn = {"chunked": att._chunked_core, "kv_chunked": att._kv_chunked_core}[core]
+    fns = {"chunked": att._chunked_core, "kv_chunked": att._kv_chunked_core}
 
     def dense(q_, k_, v_):
         return jnp.sum(att._dense_core(q_, k_, v_, mask) * cot)
 
     def chunked(q_, k_, v_):
-        return jnp.sum(fn(q_, k_, v_, 0, True, window, None, chunk) * cot)
+        if core.startswith("flash"):
+            out = att._flash_core(q_, k_, v_, window)
+        else:
+            out = fns[core](q_, k_, v_, 0, True, window, None, chunk)
+        return jnp.sum(out * cot)
 
     want, want_g = jax.value_and_grad(dense, argnums=(0, 1, 2))(q, k, v)
     got, got_g = jax.value_and_grad(chunked, argnums=(0, 1, 2))(q, k, v)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     for a, e in zip(got_g, want_g):
         np.testing.assert_allclose(a, e, rtol=1e-4, atol=1e-5)
+
+
+# The two long-sequence cells' attention calls: (arch, tokens a row, window)
+_FLASH_CALLS = {
+    "phi4mini-16k": ("phi4-mini-3.8b", 16384, None),
+    "mellum2-8k-full": ("mellum2-12b-a2.5b.ep4", 8192, None),
+    "mellum2-8k-window": ("mellum2-12b-a2.5b.ep4", 8192, 1024),
+}
+
+
+def _call(name, **over):
+    arch, s, window = _FLASH_CALLS[name]
+    kw = dict(backend="tpu", s=s, head_dim=get_arch(arch).resolved_head_dim,
+              window=window, causal=True, prefix_len=None,
+              seq_sharded=False, n_devices=1)
+    kw.update(over)
+    return kw
+
+
+@pytest.mark.parametrize("case,over,want", [
+    ("phi4mini-16k", {}, "flash"),
+    ("mellum2-8k-full", {}, "flash"),
+    ("mellum2-8k-window", {}, "flash"),
+    ("phi4mini-16k", {"s": 2048}, "dense"),
+    ("mellum2-8k-window", {"s": 2048}, "banded"),
+    ("phi4mini-16k", {"prefix_len": 256}, "chunked"),
+    ("mellum2-8k-full", {"s": 2048}, "dense"),
+    ("phi4mini-16k", {"causal": False}, "chunked"),
+    ("mellum2-8k-window", {"prefix_len": 256}, "banded"),
+    ("phi4mini-16k", {"n_devices": 4}, "chunked"),
+    ("mellum2-8k-full", {"n_devices": 4}, "chunked"),
+    ("mellum2-8k-window", {"n_devices": 4}, "banded"),
+    ("phi4mini-16k", {"n_devices": 4, "seq_sharded": True}, "kv_chunked"),
+    ("phi4mini-16k", {"backend": "cpu"}, "chunked"),
+    ("mellum2-8k-window", {"backend": "cpu"}, "banded"),
+    ("phi4mini-16k", {"head_dim": 64}, "chunked"),
+    ("phi4mini-16k", {"s": 16384 + 128}, "chunked"),
+])
+def test_pick_core_takes_flash_only_where_it_applies(case, over, want):
+    """The kernel for both long-sequence cells' attention on one TPU; the
+    present core for short sequences, prefix-LM, non-causal, a partitioned
+    mesh, the CPU, and shapes that are not whole blocks."""
+    from repro.models.attention import pick_core
+
+    assert pick_core(**_call(case, **over)) == want
+
+
+@pytest.mark.parametrize("mode,backend", [
+    ("train", "tpu"), ("train", "cpu"), ("decode", "tpu"), ("cross", "tpu")])
+def test_attn_apply_runs_the_core_it_picks(monkeypatch, mode, backend):
+    """A phi4mini layer at 2 x 16,384 traces to the Pallas kernel where the
+    backend is a TPU, and to the XLA scans elsewhere; decode against a
+    16,384-token cache and cross-attention from 16,384 tokens keep their
+    XLA cores on a TPU too (traced, not run)."""
+    from repro.models import attention as att
+    from repro.models.config import BlockSpec, FF, Mixer
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = get_arch("phi4-mini-3.8b")
+    spec = BlockSpec(Mixer.GLOBAL_ATTN, FF.SWIGLU,
+                     rope_base=None if mode == "cross" else 1e4)
+    p = jax.tree.map(lambda d: jax.ShapeDtypeStruct(d.shape, d.dtype),
+                     att.attn_defs(cfg), is_leaf=pdefs.is_def)
+    b, s = 2, 16384
+    kv = cfg.n_kv_heads * cfg.resolved_head_dim
+    x = jax.ShapeDtypeStruct((b, 1 if mode == "decode" else s, cfg.d_model),
+                             jnp.bfloat16)
+    cache = ({"k": jax.ShapeDtypeStruct((b, s, kv), jnp.bfloat16),
+              "v": jax.ShapeDtypeStruct((b, s, kv), jnp.bfloat16)}
+             if mode == "decode" else None)
+    enc = (jax.ShapeDtypeStruct((b, 1500, cfg.d_model), jnp.bfloat16)
+           if mode == "cross" else None)
+
+    def loss(p_, x_, cache_, enc_):
+        y, _ = att.attn_apply(
+            cfg, spec, p_, x_, policy=att.ShardingPolicy(), cache=cache_,
+            decode_pos=jnp.int32(s - 1) if mode == "decode" else None,
+            cross_kv=enc_)
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(p, x, cache, enc))
+    assert ("pallas_call" in text) == (mode == "train" and backend == "tpu")
+    if backend == "cpu" or mode == "cross":
+        assert "scan" in text

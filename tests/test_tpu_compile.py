@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -150,6 +151,68 @@ def test_held_expert_layer_compiles_for_v5e(one_chip, monkeypatch):
     assert text.count('custom_call_target="tpu_custom_call"') == 9
     mem = compiled.memory_analysis()
     assert 0 < mem.temp_size_in_bytes < HBM_BYTES
+
+
+# the long-sequence cells' attention calls: (B, S, KV heads, group, window)
+FLASH_SHAPES = {
+    "phi4mini-16k": (2, 16384, 8, 3, None),
+    "mellum2-8k-full": (2, 8192, 4, 8, None),
+    "mellum2-8k-window": (2, 8192, 4, 8, 1024),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FLASH_SHAPES))
+def test_flash_core_compiles_for_v5e(one_chip, monkeypatch, case):
+    """The flash core's forward and backward at the two long-sequence
+    cells' shapes: three kernel calls (forward, dq, dkv), fewer temporaries
+    than the XLA core it replaces, and every kernel call inside the scope
+    ``attn_core``, read whole (the kernel prints its op_name on a
+    continuation line) as the ``attn_roofline`` reader reads it, but not
+    as the line reader of ``attn_ms`` reads it."""
+    from bench import scopes
+    from bench.metrics.attn_roofline import joined
+    from repro.models import attention as att
+
+    # the core picks the kernel or its interpreter by the backend it runs on
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    b, s, kh, g, window = FLASH_SHAPES[case]
+    q = jax.ShapeDtypeStruct((b, s, kh, g, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, s, kh, 128), jnp.bfloat16, sharding=one_chip)
+
+    def grad_of(core):
+        def loss(q_, k_, v_):
+            return jnp.sum(core(q_, k_, v_).astype(jnp.float32))
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile()
+
+    flash = grad_of(lambda q_, k_, v_: att._flash_core(q_, k_, v_, window))
+    if window is None:
+        xla = grad_of(lambda q_, k_, v_: att._chunked_core(
+            q_, k_, v_, 0, True, None, None))
+    else:
+        xla = grad_of(lambda q_, k_, v_: att._banded_core(
+            q_, k_, v_, 0, window))
+    tmp = flash.memory_analysis().temp_size_in_bytes
+    assert 0 < tmp < xla.memory_analysis().temp_size_in_bytes
+    raw = flash.as_text()
+    text = joined(raw)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    for line in calls:
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert scopes.scope_of(op_name) == "attn_core", op_name
+    names = [re.match(r"\s*(?:ROOT )?%([\w.-]+) = ", line).group(1)
+             for line in calls]
+    found = scopes.hlo_scopes(text)
+    assert {found[n][0] for n in names} == {"attn_core"}
+    # attn_ms reads the text a line at a time and so misses the kernel's
+    # op_name: it takes the scope of a user, outside the core for the dkv
+    # call.  This pins that known gap (PERF.md, section 7); it fails once
+    # bench/scopes.py reads each instruction whole, and then attn_ms counts
+    # all of the kernel and attn_roofline can read it from bench/scopes.py
+    line_read = scopes.hlo_scopes(raw)
+    assert {line_read[n][0] for n in names} != {"attn_core"}
 
 
 def test_lm100m_isp_step_fits_one_v5e(one_chip):

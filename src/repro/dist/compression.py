@@ -17,7 +17,9 @@ Exchange schemes (``CompressionConfig.scheme``):
   dense collective. This is the correctness baseline.
 * ``topk``   — per pod, per ``block``-sized block, keep the ``budget``
   fraction of entries with the largest magnitude; everything else returns
-  to the residual (error feedback — no update mass is ever lost).
+  to the residual (error feedback — no update mass is ever lost). The
+  kept entries are combined as the other schemes are, by a dense float32
+  sum over the pod dim: on a mesh an all-reduce, with no wire saving yet.
 * ``bitmap`` — exchange the significant entries as (bitmask, packed
   values): numerically identical to ``dense`` (the same entries move),
   only the wire encoding differs — the paper's Redis sparse encoding,
@@ -31,9 +33,10 @@ live FaaS runtime's encoder asserts against, so the bytes this module
 reports to the simulator/auto-tuner equal the bytes the runtime would
 measure, by construction (DESIGN.md §10).
 
-The significance split itself reuses ``core.isp.significance_split`` (jnp
-reference) or the fused Pallas kernel ``kernels.significance`` (the hot
-path: one VMEM pass instead of >= 8 HBM passes), selected per call.
+The significance split is ``core.isp.significance_split``, which XLA fuses
+with the optimizer update on the train path. The Pallas kernel
+``kernels.significance`` takes its place only under
+``CompressionConfig.fused``, which no trainer mode sets.
 """
 
 from __future__ import annotations
@@ -185,44 +188,6 @@ def _argmax_mask(blocks: jax.Array) -> jax.Array:
     """True at the first largest |entry| of each last-axis block."""
     idx = jnp.argmax(jnp.abs(blocks), axis=-1)
     return jnp.arange(blocks.shape[-1]) == idx[..., None]
-
-
-def topk_combine(cfg: CompressionConfig, sig_pod: PyTree, n_pods: int) -> PyTree:
-    """Row-top-k compact exchange, GSPMD-auto and sharding-preserving.
-
-    Per leaf: (n_pods, *shape) pod-sharded significant updates -> per-pod
-    top-k per LAST-AXIS ROW (values, indices) -> scan over pods slicing the
-    compact arrays (only compact bytes cross 'pod') -> put_along_axis into
-    a dense accumulator that keeps the leaf's natural leading-dim sharding.
-
-    Two refuted formulations led here (EXPERIMENTS.md §Perf c2/c3): a
-    replicated (nb, block) accumulator makes GSPMD reshard the dense tensor
-    per pod, and ANY full flatten (`reshape(n_pods, -1)`) collapses the 2D
-    parameter sharding, which GSPMD resolves by gathering the entire f32
-    update across pods (51 GB/chip measured). Rows along the original last
-    axis preserve every sharded dim.
-    """
-
-    def leaf(s):
-        last = s.shape[-1]
-        kk = cfg.k_per_block(last)
-        _, idx = jax.lax.top_k(jnp.abs(s), kk)  # (P, *lead, kk)
-        vals = jnp.take_along_axis(s, idx, axis=-1)
-
-        def add_pod(acc, pi):
-            v = jax.lax.dynamic_index_in_dim(vals, pi, 0, keepdims=False)
-            i = jax.lax.dynamic_index_in_dim(idx, pi, 0, keepdims=False)
-            upd = jnp.put_along_axis(
-                jnp.zeros_like(acc), i, v, axis=-1, inplace=False
-            )
-            return acc + upd, None
-
-        acc, _ = jax.lax.scan(
-            add_pod, jnp.zeros(s.shape[1:], s.dtype), jnp.arange(n_pods)
-        )
-        return acc
-
-    return jax.tree.map(leaf, sig_pod)
 
 
 def isp_compressed_step(

@@ -1,1 +1,1 @@
-"""Launchers: production mesh, allocation-free dry-run, train/serve drivers."""
+"""Launchers: production mesh, allocation-free dry-run, train driver."""

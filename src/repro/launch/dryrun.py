@@ -18,7 +18,8 @@ Per cell this driver:
   1. builds the mesh (16x16 single-pod or 2x16x16 multi-pod),
   2. resolves abstract params/optimizer/cache/batch structs + shardings
      (zero allocation — everything is ShapeDtypeStruct),
-  3. jit-lowers the real step function (the same one the drivers run),
+  3. jit-lowers the step the trainer runs (``launch.train.make_step`` /
+     ``make_pod_step``), its shardings carried by its arguments,
   4. compiles, prints memory_analysis() (proof-of-fit) and cost_analysis(),
   5. parses collective wire bytes from the optimized HLO,
   6. writes the roofline record to results/dryrun/<cell>.json.
@@ -44,32 +45,30 @@ from repro.core.isp import ISPConfig
 from repro.dist.compression import CompressionConfig
 from repro.launch.hloanalysis import analyze_hlo
 from repro.launch.mesh import make_production_mesh
-from repro.launch.roofline import Roofline, model_flops, parse_collectives
+from repro.launch.roofline import Roofline, model_flops
+from repro.launch import train
 from repro.launch.specs import build_cell, opt_state_defs
-from repro.launch.steps import (
-    make_decode_step,
-    make_isp_train_step,
-    make_prefill_step,
-    make_train_step,
-)
 from repro.models import params as pdefs
 from repro.models.config import SHAPES, shape_applicable
 from repro.configs import get_arch
 from repro import optim
 
 
-def _shardings(mesh, specs):
-    from jax.sharding import NamedSharding, PartitionSpec as P
+def _sharded(mesh, structs, specs):
+    """``structs`` with each leaf placed by its ``specs`` entry: the step
+    takes its shardings from its arguments."""
+    from jax.sharding import NamedSharding
 
     return jax.tree.map(
-        lambda s: NamedSharding(mesh, s), specs,
-        is_leaf=lambda x: isinstance(x, P),
+        lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, s)),
+        structs, specs,
     )
 
 
 def dataclasses_replace_policy_strip_pod(lm):
-    """LM with 'pod' removed from every policy axis (for the ISP step's
-    per-pod inner function, where 'pod' is shard_map-manual)."""
+    """LM with 'pod' removed from every policy axis (for the pod step's
+    per-pod inner function, vmapped over the pod dim)."""
     import dataclasses
 
     def strip(ax):
@@ -108,6 +107,10 @@ def lower_cell(
 ):
     """Returns (lowered, compiled, cell, mesh). Raises on inapplicable.
 
+    A train cell lowers the step the trainer runs: ``train.make_step``
+    (``bsp``) or ``train.make_pod_step`` (``isp-*``, the pod dim sharded
+    over 'pod').
+
     ``n_pods`` overrides the production mesh with an elastic pool size
     (``dist.elastic.mesh_shape_for`` at 16x16 chips per pod) — the shape a
     scaled-in job re-lowers for after an auto-tuner eviction.
@@ -125,105 +128,53 @@ def lower_cell(
     lm = cell.lm
     optimizer = optim.make("adam", 1e-3)
 
-    p_structs = cell.param_structs()
-    p_specs = cell.param_specs()
-    b_shardings = _shardings(mesh, cell.batch_specs)
+    def sharded_defs(defs):
+        return _sharded(mesh, pdefs.to_struct(defs), pdefs.to_specs(defs))
+
+    params = sharded_defs(cell.param_defs)
+    batch = _sharded(mesh, cell.batch_structs, cell.batch_specs)
 
     if cell.shape.kind == "train":
         o_defs = opt_state_defs(cell.param_defs)
-        o_structs = pdefs.to_struct(o_defs)
-        o_specs = pdefs.to_specs(o_defs)
         if mode == "bsp":
-            step = make_train_step(lm, optimizer)
-            jitted = jax.jit(
-                step,
-                in_shardings=(
-                    _shardings(mesh, p_specs),
-                    _shardings(mesh, o_specs),
-                    b_shardings,
-                ),
-                donate_argnums=(0, 1),
-            )
-            args = (p_structs, o_structs, cell.batch_structs)
+            step = train.make_step(lm, optimizer, None)
+            args = (params, sharded_defs(o_defs), None, batch)
         elif mode.startswith("isp"):
             assert multi_pod, "ISP mode compresses across the pod axis"
             n_pods = mesh.shape["pod"]
-            scheme = _ISP_SCHEMES.get(mode, "dense")
-            # inside shard_map over 'pod' the pod axis is MANUAL — the
-            # model's sharding constraints must not mention it
-            lm_inner = dataclasses_replace_policy_strip_pod(lm)
-            step = make_isp_train_step(
-                lm_inner, optimizer, mesh,
+            # the pod dim is vmapped: the model's sharding constraints
+            # inside the per-pod function must not mention 'pod'
+            step = train.make_pod_step(
+                dataclasses_replace_policy_strip_pod(lm), optimizer,
                 ISPConfig(v=0.7),
-                CompressionConfig(scheme=scheme, budget=budget),
+                CompressionConfig(scheme=_ISP_SCHEMES[mode], budget=budget),
+                n_pods,
             )
-            lift = lambda d: pdefs.stack(d, n_pods)
 
-            def podspec(defs):
-                return jax.tree.map(
-                    lambda x: type(x)(*(("pod",) + tuple(x)[1:])),
-                    pdefs.to_specs(defs),
+            def pod_sharded(defs):
+                stacked = pdefs.stack(defs, n_pods)
+                specs = jax.tree.map(
+                    lambda x: P("pod", *tuple(x)[1:]),
+                    pdefs.to_specs(stacked),
                     is_leaf=lambda s: isinstance(s, P),
                 )
+                return _sharded(mesh, pdefs.to_struct(stacked), specs)
 
-            o_defs_pod = lift(o_defs)
-            r_defs_pod = lift(cell.param_defs)
-            jitted = jax.jit(
-                step,
-                in_shardings=(
-                    _shardings(mesh, p_specs),
-                    _shardings(mesh, podspec(o_defs_pod)),
-                    _shardings(mesh, podspec(r_defs_pod)),
-                    b_shardings,
-                ),
-                donate_argnums=(0, 1, 2),
-            )
-            args = (
-                p_structs,
-                pdefs.to_struct(o_defs_pod),
-                pdefs.to_struct(r_defs_pod),
-                cell.batch_structs,
-            )
+            args = (params, pod_sharded(o_defs),
+                    pod_sharded(cell.param_defs), batch)
         else:
             raise ValueError(mode)
     elif cell.shape.kind == "prefill":
-        step = make_prefill_step(lm)
-        c_structs = pdefs.to_struct(cell.cache_defs)
-        c_specs = pdefs.to_specs(cell.cache_defs)
-        jitted = jax.jit(
-            step,
-            in_shardings=(
-                _shardings(mesh, p_specs),
-                _shardings(mesh, c_specs),
-                b_shardings,
-            ),
-            donate_argnums=(1,),
-        )
-        args = (p_structs, c_structs, cell.batch_structs)
+        step = jax.jit(lm.prefill, donate_argnums=(1,))
+        args = (params, sharded_defs(cell.cache_defs), batch)
     else:  # decode
-        step = make_decode_step(lm)
-        c_structs = pdefs.to_struct(cell.cache_defs)
-        c_specs = pdefs.to_specs(cell.cache_defs)
-        jitted = jax.jit(
-            step,
-            in_shardings=(
-                _shardings(mesh, p_specs),
-                _shardings(mesh, c_specs),
-                b_shardings,
-                None,
-            ),
-            donate_argnums=(1,),
-        )
-        args = (
-            p_structs,
-            c_structs,
-            cell.batch_structs,
-            jax.ShapeDtypeStruct((), jnp.int32),
-        )
+        step = jax.jit(lm.decode_step, donate_argnums=(1,))
+        args = (params, sharded_defs(cell.cache_defs), batch,
+                jax.ShapeDtypeStruct((), jnp.int32))
 
     with mesh:
         t0 = time.time()
-        lowered = jitted.lower(*args)
+        lowered = step.lower(*args)
         t1 = time.time()
         compiled = lowered.compile()
         t2 = time.time()

@@ -188,9 +188,10 @@ def make_pod_step(
     pod axis; each pod runs its own optimizer on its own shard (divergent
     moments), then the error-feedback compressed exchange
     (``dist.compression.isp_compressed_step``) combines the significant
-    parts into the shared parameters. This is the single-host vmap
-    analogue of the GSPMD formulation in ``launch.steps``; on a real
-    multi-pod mesh the leading dim shards over 'pod'.
+    parts into the shared parameters. It is the one pod step: the trainer
+    runs it under ``vmap`` on one device, and ``launch.dryrun`` lowers it
+    with the leading dim sharded over 'pod', where the sum over pods is a
+    dense all-reduce.
     """
 
     def step_fn(params, opt_pod, res_pod, batch):

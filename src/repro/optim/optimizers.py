@@ -41,8 +41,9 @@ def sgd(lr: float, lr_decay: bool = False) -> Optimizer:
     """Plain SGD: u_t = -eta_t * g_t."""
 
     def init(params: PyTree) -> OptState:
-        z = _zeros_like_tree(params)
-        return OptState(jnp.asarray(1, jnp.int32), z, z)
+        # two trees, not one twice: a train step donates its optimizer state
+        return OptState(jnp.asarray(1, jnp.int32), _zeros_like_tree(params),
+                        _zeros_like_tree(params))
 
     def update(grads: PyTree, state: OptState, params: PyTree):
         eta = _lr_at(lr, state.step, lr_decay)
@@ -59,8 +60,9 @@ def nesterov(lr: float, momentum: float = 0.9, lr_decay: bool = False) -> Optimi
     """
 
     def init(params: PyTree) -> OptState:
-        z = _zeros_like_tree(params)
-        return OptState(jnp.asarray(1, jnp.int32), z, z)
+        # two trees, not one twice: a train step donates its optimizer state
+        return OptState(jnp.asarray(1, jnp.int32), _zeros_like_tree(params),
+                        _zeros_like_tree(params))
 
     def update(grads: PyTree, state: OptState, params: PyTree):
         eta = _lr_at(lr, state.step, lr_decay)

@@ -13,7 +13,7 @@ import pytest
 from repro import optim
 from repro.configs import ARCH_NAMES, get_arch, get_smoke
 from repro.launch.specs import opt_state_defs
-from repro.launch.steps import make_train_step
+from repro.launch.train import make_step
 from repro.models import params as pdefs
 from repro.models.transformer import LM
 
@@ -93,15 +93,17 @@ def test_smoke_train_step(name):
     cfg = get_smoke(name)
     lm = LM(cfg)
     params = lm.init(jax.random.PRNGKey(0))
-    step = jax.jit(make_train_step(lm, optim.make("adam", 1e-3)))
-    p2, o2, loss, metrics = step(params, _opt_state(lm), _batch(cfg))
-    assert np.isfinite(float(loss)), name
+    batch = _batch(cfg)
+    _, metrics = jax.jit(lm.train_loss)(params, batch)
     assert np.isfinite(float(metrics["xent"]))
+    d0 = np.asarray(jax.tree.leaves(params)[0], np.float32)
+    # the step donates params and optimizer state
+    step = make_step(lm, optim.make("adam", 1e-3), None)
+    p2, o2, _, loss, _ = step(params, _opt_state(lm), None, batch)
+    assert np.isfinite(float(loss)), name
     # params actually changed
-    d0 = jax.tree.leaves(params)[0]
-    d1 = jax.tree.leaves(p2)[0]
-    assert not np.allclose(np.asarray(d0, np.float32),
-                           np.asarray(d1, np.float32))
+    d1 = np.asarray(jax.tree.leaves(p2)[0], np.float32)
+    assert not np.allclose(d0, d1)
 
 
 @pytest.mark.parametrize("name", ARCH_NAMES)
@@ -163,12 +165,12 @@ def test_loss_decreases_when_training():
 
     lm = LM(LM_8M)
     params = lm.init(jax.random.PRNGKey(0))
-    step = jax.jit(make_train_step(lm, optim.make("adam", 1e-3)))
+    step = make_step(lm, optim.make("adam", 1e-3), None)
     opt = _opt_state(lm)
     pipe = TokenPipeline(LM_8M.vocab_size, 128, 8, seed=0)
     losses = []
     for i in range(30):
-        params, opt, loss, _ = step(params, opt, pipe.next_batch(i))
+        params, opt, _, loss, _ = step(params, opt, None, pipe.next_batch(i))
         losses.append(float(loss))
     assert losses[-1] < losses[0] - 0.3, (losses[0], losses[-1])
 

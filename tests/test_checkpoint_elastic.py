@@ -67,8 +67,7 @@ def test_train_restore_continues_bit_exact(tmp_path):
     from repro import optim
     from repro.data.tokens import TokenPipeline
     from repro.launch.specs import opt_state_defs
-    from repro.launch.steps import make_train_step
-    from repro.launch.train import LM_8M
+    from repro.launch.train import LM_8M, make_step
     from repro.models import params as pdefs
     from repro.models.transformer import LM
     import dataclasses
@@ -81,23 +80,27 @@ def test_train_restore_continues_bit_exact(tmp_path):
     opt_state = jax.tree.map(
         lambda d: jnp.zeros(d.shape, d.dtype) if d.init == "zeros"
         else jnp.ones(d.shape, d.dtype), o_defs, is_leaf=pdefs.is_def)
-    step = jax.jit(make_train_step(lm, optim.make("adam", 1e-3)))
+    # the step donates params and optimizer state: branch A steps on the
+    # arrays themselves, and the restore reads only their shapes
+    step = make_step(lm, optim.make("adam", 1e-3), None)
     pipe = TokenPipeline(cfg.vocab_size, 32, 2, seed=0)
 
     for i in range(3):
-        params, opt_state, *_ = step(params, opt_state, pipe.next_batch(i))
-    ckpt.save(str(tmp_path), 3, {"p": params, "o": opt_state})
+        params, opt_state, *_ = step(params, opt_state, None,
+                                     pipe.next_batch(i))
+    like = {"p": params, "o": opt_state}
+    ckpt.save(str(tmp_path), 3, like)
 
     # branch A: continue in-process
     pa, oa = params, opt_state
     for i in range(3, 6):
-        pa, oa, *_ = step(pa, oa, pipe.next_batch(i))
+        pa, oa, *_ = step(pa, oa, None, pipe.next_batch(i))
 
     # branch B: restore (simulated restart) then continue
-    restored = ckpt.restore(str(tmp_path), 3, {"p": params, "o": opt_state})
+    restored = ckpt.restore(str(tmp_path), 3, like)
     pb, ob = restored["p"], restored["o"]
     for i in range(3, 6):
-        pb, ob, *_ = step(pb, ob, pipe.next_batch(i))
+        pb, ob, *_ = step(pb, ob, None, pipe.next_batch(i))
 
     for a, b in zip(jax.tree.leaves(pa), jax.tree.leaves(pb)):
         np.testing.assert_array_equal(np.asarray(a, np.float32),

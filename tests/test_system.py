@@ -1,4 +1,4 @@
-"""End-to-end system tests: the train and serve drivers, ISP + autotuner."""
+"""End-to-end system tests: the train driver, the pod step, ISP + autotuner."""
 
 import argparse
 import dataclasses
@@ -51,53 +51,62 @@ def test_train_driver_autotuner_scales_in():
     assert res["final_pool"] <= 3
 
 
-def test_serve_driver_end_to_end():
-    from repro.launch import serve as S
-
-    ns = argparse.Namespace(
-        arch="xlstm-1.3b", smoke=True, requests=4, slots=2, prompt_len=16,
-        gen_len=4, seed=0, out=None,
-    )
-    res = S.serve(ns)
-    assert res["new_tokens"] > 0
-    assert res["decode_tokens_per_s"] > 0
-
-
-def test_isp_step_matches_bsp_at_v0():
-    """launch.steps.make_isp_train_step with v=0 must track plain BSP
-    params after one step (Corollary 1, pod form; n_pods=1 degenerate)."""
-    from repro import optim
-    from repro.core.isp import ISPConfig
-    from repro.dist.compression import CompressionConfig
-    from repro.launch.mesh import make_mesh
-    from repro.launch.steps import make_isp_train_step, make_train_step
+def _tiny_lm(param_dtype="bfloat16"):
     from repro.launch.train import LM_8M
     from repro.models.transformer import LM
 
-    cfg = dataclasses.replace(
+    return LM(dataclasses.replace(
         LM_8M, name="tiny", d_model=64, n_heads=4, n_kv_heads=4, d_ff=128,
-        vocab_size=512,
-    )
-    lm = LM(cfg)
-    params = lm.init(jax.random.PRNGKey(0))
-    optimizer = optim.make("sgd", 0.1)
-    batch = {
+        vocab_size=512, param_dtype=param_dtype,
+    ))
+
+
+def _tiny_batch():
+    return {
         "tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, 512),
         "labels": jax.random.randint(jax.random.PRNGKey(2), (4, 32), 0, 512),
     }
-    # BSP reference
-    bsp = make_train_step(lm, optimizer, clip_norm=0.0)
-    p_bsp, *_ = jax.jit(bsp)(params, optimizer.init(params), batch)
 
-    mesh = make_mesh((1,), ("pod",))
-    isp = make_isp_train_step(
-        lm, optimizer, mesh, ISPConfig(v=0.0, decay=False),
-        CompressionConfig(scheme="dense"), clip_norm=0.0,
-    )
-    lift = lambda t: jax.tree.map(lambda x: x[None], t)
-    p_isp, *_ = jax.jit(isp)(
-        params, lift(optimizer.init(params)),
-        lift(jax.tree.map(jnp.zeros_like, params)), batch,
+
+@pytest.mark.parametrize("name", ["sgd", "nesterov", "adam"])
+def test_step_runs_with_each_optimizer(name):
+    """The train step donates its optimizer state, so every optimizer's
+    state must hold each buffer once."""
+    from repro import optim
+    from repro.launch.train import make_step
+
+    lm = _tiny_lm()
+    params = lm.init(jax.random.PRNGKey(0))
+    x0 = np.asarray(jax.tree.leaves(params)[0], np.float32)
+    optimizer = optim.make(name, 0.1)
+    p1, _, _, loss, _ = make_step(lm, optimizer, None)(
+        params, optimizer.init(params), None, _tiny_batch())
+    assert np.isfinite(float(loss))
+    assert not np.allclose(x0, np.asarray(jax.tree.leaves(p1)[0], np.float32))
+
+
+@pytest.mark.parametrize("scheme", ["dense", "bitmap"])
+def test_isp_step_matches_bsp_at_v0(scheme):
+    """The pod step with v=0 must track plain BSP params after one step
+    (Corollary 1, pod form; n_pods=1 degenerate)."""
+    from repro import optim
+    from repro.core.isp import ISPConfig
+    from repro.dist.compression import CompressionConfig
+    from repro.launch.train import lift_pod, make_pod_step, make_step
+
+    lm = _tiny_lm()
+    params = lm.init(jax.random.PRNGKey(0))
+    optimizer = optim.make("sgd", 0.1)
+    batch = _tiny_batch()
+    # both steps donate their state: each gets its own copy
+    copy = lambda t: jax.tree.map(jnp.copy, t)
+    p_bsp, *_ = make_step(lm, optimizer, None)(
+        copy(params), optimizer.init(params), None, batch)
+    pod = make_pod_step(lm, optimizer, ISPConfig(v=0.0, decay=False),
+                        CompressionConfig(scheme=scheme), 1)
+    p_isp, *_ = pod(
+        copy(params), lift_pod(optimizer.init(params), 1),
+        lift_pod(jax.tree.map(jnp.zeros_like, params), 1), batch,
     )
     for a, b in zip(jax.tree.leaves(p_bsp), jax.tree.leaves(p_isp)):
         np.testing.assert_allclose(np.asarray(a, np.float32),
@@ -105,18 +114,59 @@ def test_isp_step_matches_bsp_at_v0():
                                    atol=2e-3)
 
 
-def test_topk_combine_moves_only_budgeted_entries():
+@pytest.mark.parametrize("scheme", ["dense", "topk", "bitmap"])
+def test_pod_step_conserves_update_mass_over_two_pods(scheme):
+    """Over two pods, the pod step moves every pod's update and residual
+    into params and new residuals with no mass made or lost:
+    ``params' - params == sum_p sent_p`` with
+    ``sent_p == r_p + u_p - r_p'``, each entry of ``sent_p`` is all of
+    ``r_p + u_p`` there or nothing, and ``topk`` sends at most one entry
+    of each pod's 128-entry blocks.  ``u_p`` is recomputed outside the
+    step from each pod's half of the batch."""
+    from repro import optim
+    from repro.core.isp import ISPConfig
     from repro.dist.compression import CompressionConfig
-    from repro.launch.steps import _topk_combine
+    from repro.launch.train import lift_pod, make_pod_step
+    from repro.optim import clip_by_global_norm
 
-    cfg = CompressionConfig(scheme="topk", budget=0.01, block=128)
-    sig = {"w": jax.random.normal(jax.random.PRNGKey(0), (2, 1024))}
-    out = _topk_combine(cfg, sig, 2)
-    nz = int(jnp.sum(out["w"] != 0))
-    # per pod: one 1024-row x k=10 -> at most 20 nonzeros after the combine
-    assert nz <= 20
-    assert np.isfinite(np.asarray(out["w"])).all()
-    # the kept entries are each pod's row maxima
-    a = np.asarray(sig["w"])
-    want_top = np.abs(a[0]).max()
-    assert np.abs(np.asarray(out["w"])).max() >= want_top * 0.5
+    lm = _tiny_lm("float32")
+    optimizer = optim.make("adam", 1e-2)
+    isp = ISPConfig(v=0.7)
+    step = make_pod_step(lm, optimizer, isp, CompressionConfig(
+        scheme=scheme, budget=0.01, block=128), 2)
+    params = lm.init(jax.random.PRNGKey(0))
+    opt = lift_pod(optimizer.init(params), 2)
+    res = lift_pod(jax.tree.map(jnp.zeros_like, params), 2)
+    # a first step leaves residuals behind; the second is the one checked
+    params, opt, res, *_ = step(params, opt, res, _tiny_batch())
+    copy = lambda t: jax.tree.map(jnp.copy, t)
+    x0, opt0, r0 = copy(params), copy(opt), copy(res)
+    batch = {k: v[::-1] for k, v in _tiny_batch().items()}
+    x1, _, r1, _, sent_frac = step(params, opt, res, batch)
+
+    def pod_update(o, b):
+        grads = jax.grad(lambda p: lm.train_loss(p, b)[0])(x0)
+        grads = clip_by_global_norm(grads, 1.0)
+        return optimizer.update(grads, o, x0)[0]
+
+    halves = jax.tree.map(lambda v: v.reshape((2, 2) + v.shape[1:]), batch)
+    u = jax.jit(jax.vmap(pod_update))(opt0, halves)
+    leaves = lambda t: [np.asarray(l, np.float64) for l in jax.tree.leaves(t)]
+    n_sent = 0
+    for x, x_new, u_p, r_p, r_new in zip(
+            leaves(x0), leaves(x1), leaves(u), leaves(r0), leaves(r1)):
+        acc = r_p + u_p
+        sent = acc - r_new
+        tol = 1e-6 + 1e-4 * np.abs(acc)
+        np.testing.assert_allclose(x_new - x, sent.sum(0), rtol=0,
+                                   atol=1e-6)
+        assert (np.minimum(np.abs(sent), np.abs(acc - sent)) <= tol).all()
+        kept = np.abs(sent) > tol
+        n_sent += int(kept.sum())
+        if scheme == "topk":
+            flat = kept.reshape(2, -1)
+            pad = (-flat.shape[1]) % 128
+            blocks = np.pad(flat, ((0, 0), (0, pad))).reshape(2, -1, 128)
+            assert (blocks.sum(-1) <= 1).all()
+    assert n_sent > 0
+    assert 0.0 < float(sent_frac) < 1.0

@@ -243,6 +243,54 @@ def test_lm100m_isp_step_fits_one_v5e(one_chip):
     assert 0 < total < HBM_BYTES, total
 
 
+@pytest.mark.parametrize("mode", ["bsp", "isp-pod"])
+def test_train_step_compiles_on_a_v5e_2x2_mesh(one_chip, topo, mode):
+    """The trainer's step for four chips of a described ``v5e:2x2``, its
+    shardings carried by its arguments as ``launch.dryrun`` passes them:
+    ``bsp`` with the batch over a 4-way ``data`` axis, and the ``isp-pod``
+    ``topk`` step with the pod state and the batch over a 4-way ``pod``
+    axis and the params replicated, whose sum over pods is an all-reduce."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro import optim
+    from repro.core.isp import ISPConfig
+    from repro.dist.compression import CompressionConfig
+    from repro.launch.train import LM_8M, lift_pod, make_pod_step, make_step
+    from repro.models.transformer import LM
+
+    axis = "data" if mode == "bsp" else "pod"
+    mesh = Mesh(np.array(topo.devices).reshape(4), (axis,))
+    lm = LM(LM_8M)
+    optimizer = optim.make("adam", 3e-4)
+    params = jax.eval_shape(lm.init, jax.random.PRNGKey(0))
+    opt0 = jax.eval_shape(optimizer.init, params)
+
+    def placed(tree, spec):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec)), tree)
+
+    def per_pod(tree):
+        return placed(jax.eval_shape(lambda t: lift_pod(t, 4), tree),
+                      P("pod"))
+
+    batch = placed({k: jax.ShapeDtypeStruct((16, 256), jnp.int32)
+                    for k in ("tokens", "labels")}, P(axis))
+    if mode == "bsp":
+        step = make_step(lm, optimizer, None)
+        args = (placed(params, P()), placed(opt0, P()), None, batch)
+    else:
+        step = make_pod_step(lm, optimizer, ISPConfig(v=0.7),
+                             CompressionConfig(scheme="topk", budget=0.01), 4)
+        args = (placed(params, P()), per_pod(opt0), per_pod(params), batch)
+    compiled = step.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 0 < total < HBM_BYTES, total
+    if mode == "isp-pod":
+        assert re.search(r"\ball-reduce(-start)?\(", compiled.as_text())
+
+
 # -- CPU: compile-cache placement and chip_smoke.py --------------------------
 
 
